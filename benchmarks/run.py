@@ -43,78 +43,29 @@ def kernel_rows(quick=False):
         jax.random.normal(k, (8, 512, 1024))))
     rows.append(("kernel.group_norms_8x512x1024", us,
                  f"GB/s={8*512*1024*4/us/1e3:.1f}"))
-    x4 = jax.random.normal(k, (2, 256, 16, 32))
-    dt = jax.nn.softplus(jax.random.normal(k, (2, 256, 16)))
-    A = -jnp.exp(jax.random.normal(k, (16,)) * 0.3)
-    Bm = jax.random.normal(k, (2, 256, 32))
-    us = _timed(lambda: ops.ssd_chunk_scan(x4, dt, A, Bm, Bm, chunk=64,
-                                           block_h=8))
-    rows.append(("kernel.ssd_scan_T256", us, "interpret-mode on CPU"))
     return rows
 
 
 def wire_codec_rows(quick=False):
-    """Wire-transform microbenchmarks, two comparisons per codec op:
-
-    * the production ``kernels.ops`` route vs the interpret-mode Pallas
-      kernel (the ops.py backend-routing: off-TPU the shims dispatch to
-      the bit-identical jnp references so production executables never
-      trace through the Pallas interpreter — a conformance vehicle, not
-      a contract.  In-context the two compile to comparable code on CPU
-      (the round rows below are the decision evidence); standalone op
-      costs differ either way at these sizes, so read the ratio as
-      context, not as the routing's justification);
-    * the one-pass encode vs the stock two-pass (gather, then quantize)
-      composition it replaced."""
-    from repro.kernels import ops, wire
+    """Wire-transform microbenchmarks through the ``kernels.ops`` shims:
+    the compact+q8 / compact+q4 encode (XLA gather, then the quantize
+    kernel) and decode (XLA dequantize + zero-fill)."""
+    from repro.kernels import ops
     k = jax.random.PRNGKey(0)
     R, C, B = (256, 2048, 1024) if not quick else (64, 512, 256)
     x = jax.random.normal(k, (R, C))
     idx = jnp.sort(jax.random.permutation(k, C)[:B]).astype(jnp.int32)
-    inv = jnp.full((C,), B, jnp.int32).at[idx].set(
-        jnp.arange(B, dtype=jnp.int32))
     rows = []
-
-    i_enc8 = jax.jit(lambda a, i: wire.gather_quantize(a, i, interpret=True))
-    us_o = _timed(lambda: ops.gather_quantize(x, idx))
-    us_i = _timed(lambda: i_enc8(x, idx))
-    us_s = _timed(lambda: ops.quantize_rows(ops.gather_rows(x, idx)))
-    rows.append((f"wire.q8_encode_{R}x{C}to{B}", us_o,
-                 f"interp_kernel={us_i:.0f}us stock_2pass={us_s:.0f}us "
-                 f"interp_ratio={us_i/us_o:.2f}x"))
+    us = _timed(lambda: ops.quantize_rows(ops.gather_rows(x, idx)))
+    rows.append((f"wire.q8_encode_{R}x{C}to{B}", us, ""))
     q, s = ops.gather_quantize(x, idx)
-
-    def stock_q8_decode():
-        dec = ops.dequantize_rows(q, s)
-        return ops.gather_rows(jnp.pad(dec, ((0, 0), (0, 1))), inv)
-
-    i_dec8 = jax.jit(lambda a, b, i: wire.gather_dequantize(
-        jnp.pad(a, ((0, 0), (0, 1))), b, i, interpret=True))
-    us_o = _timed(lambda: ops.scatter_dequantize(q, s, idx, C))
-    us_i = _timed(lambda: i_dec8(q, s, inv))
-    us_s = _timed(stock_q8_decode)
-    rows.append((f"wire.q8_decode_{R}x{B}to{C}", us_o,
-                 f"interp_kernel={us_i:.0f}us stock_2pass={us_s:.0f}us "
-                 f"interp_ratio={us_i/us_o:.2f}x"))
-
-    i_enc4 = jax.jit(lambda a, i: wire.gather_quantize_q4(
-        a, i, interpret=True))
-    us_o = _timed(lambda: ops.gather_quantize_q4(x, idx))
-    us_i = _timed(lambda: i_enc4(x, idx))
-    us_s = _timed(lambda: ops.quantize_pack_q4(ops.gather_rows(x, idx)))
-    rows.append((f"wire.q4_encode_{R}x{C}to{B}", us_o,
-                 f"interp_kernel={us_i:.0f}us stock_2pass={us_s:.0f}us "
-                 f"interp_ratio={us_i/us_o:.2f}x"))
+    us = _timed(lambda: ops.scatter_dequantize(q, s, idx, C))
+    rows.append((f"wire.q8_decode_{R}x{B}to{C}", us, ""))
+    us = _timed(lambda: ops.quantize_pack_q4(ops.gather_rows(x, idx)))
+    rows.append((f"wire.q4_encode_{R}x{C}to{B}", us, ""))
     p, s4 = ops.gather_quantize_q4(x, idx)
-    inv4 = jnp.full((C,), 2 * p.shape[1], jnp.int32).at[idx].set(
-        jnp.arange(B, dtype=jnp.int32))
-    i_dec4 = jax.jit(lambda a, b, i: wire.unpack_gather_dequantize_q4(
-        jnp.pad(a, ((0, 0), (0, 1))), b, i, interpret=True))
-    us_o = _timed(lambda: ops.scatter_dequantize_q4(p, s4, idx, C))
-    us_i = _timed(lambda: i_dec4(p, s4, inv4))
-    rows.append((f"wire.q4_decode_{R}x{B}to{C}", us_o,
-                 f"interp_kernel={us_i:.0f}us "
-                 f"interp_ratio={us_i/us_o:.2f}x "
+    us = _timed(lambda: ops.scatter_dequantize_q4(p, s4, idx, C))
+    rows.append((f"wire.q4_decode_{R}x{B}to{C}", us,
                  f"packed payload={p.nbytes + s4.nbytes}B vs "
                  f"f32 {R * B * 4}B"))
     return rows

@@ -24,8 +24,8 @@ Registered codecs (``get_codec`` specs):
                    of shifts, dequant-accumulated in f32 (beyond-paper
                    §Perf; was ``comm_quant="int8"``)
   ``q4``           packed 4-bit symmetric quantization: two channels per
-                   byte + per-row f32 scales, packed/unpacked in-kernel
-                   (kernels/wire.py)
+                   byte + per-row f32 scales, quantized and packed
+                   in-kernel (kernels/wire.py)
   ``topk:<rate>``  per-member magnitude top-``rate`` sparsification with
                    error feedback; values+int32-index payloads with
                    AllGather semantics (the DGC baseline, paper §5.1.4)
@@ -33,11 +33,12 @@ Registered codecs (``get_codec`` specs):
                    element codec (``compact+q8``) to request the
                    H-SADMM physically-shrunk buffer at that boundary
 
-Quantizing codecs route encode/decode through the fused Pallas wire
-kernels (``kernels.ops`` dispatch shims over ``kernels/wire.py``): one
-streaming pass computes the per-row abs-max in VMEM and quantizes (and,
-for the compact path, gathers kept groups) on the way out; decode is
-the mirrored dequantize + zero-fill expansion.  Scale granularity is
+Quantizing codecs encode through the fused Pallas wire kernels
+(``kernels.ops`` shims over ``kernels/wire.py``): one streaming pass
+computes the per-row abs-max in VMEM and quantizes (q4: and packs) on
+the way out.  Decode, and the kept-group gather/zero-fill of the
+compact encode/decode, are XLA ops that fuse into their consumers
+(Mosaic has no lane gather).  Scale granularity is
 per ROW of the (R, C) view — a function of the leaf shape only, so
 ``wire_bytes`` stays analytic (DESIGN.md "Per-row wire scales").
 
@@ -144,9 +145,8 @@ class WireCodec:
     # ---- fused compact wire path (canonical (R, C) 2-D view) ------------ #
     def encode_compact(self, leaf2d: jnp.ndarray, idx: jnp.ndarray):
         """Kept-group gather along the minor axis + encode of a (R, C)
-        leaf — the §4.4 packing fused with this codec's element format.
-        Quantizing codecs override with a single-pass Pallas kernel; the
-        base gathers (one kernel pass) and encodes the result."""
+        leaf — the §4.4 packing composed with this codec's element
+        format: gather the kept channels, then encode."""
         from ..kernels import ops
         return self.encode(ops.gather_rows(leaf2d, idx))
 
@@ -205,8 +205,8 @@ class Q8Codec(WireCodec):
     Each leaf is scaled per row of its (R, C) 2-D view to int8 (+ one
     f32 scale per row), exchanged across the group via a ring of shifts
     over the leading dim, and dequant-accumulated in f32 locally.
-    Encode/decode run through the fused Pallas wire kernels (abs-max in
-    VMEM + quantize in one pass; ``kernels.ops`` shims).  Slow-fabric
+    Encode runs through the fused Pallas wire kernel (abs-max in VMEM +
+    quantize in one pass; ``kernels.ops`` shim).  Slow-fabric
     bytes drop 2x vs bf16 / 4x vs f32 payloads; quantization error is
     bounded by max|row|/127 per row — at most the old per-leaf
     max|x|/127 bound — and is absorbed by the ADMM duals
@@ -267,8 +267,8 @@ class Q4Codec(WireCodec):
 
     Rows of the (R, C) leaf view quantize to [-7, 7] (two's-complement
     nibbles, one f32 scale per row) and pack pairwise into uint8 —
-    quantize + pack fused in one Pallas pass, unpack + dequant (+
-    zero-fill expansion on the compact path) fused on decode.  The ring
+    quantize + pack fused in one Pallas pass; decode unpacks and
+    dequantizes in XLA.  The ring
     exchange rolls the PACKED buffer, so the bytes that cross the fabric
     are exactly ``wire_bytes`` = rows * (ceil(C/2) + 4).  Odd minor dims
     carry one zero pad nibble (trimmed on decode via the dense

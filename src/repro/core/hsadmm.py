@@ -16,11 +16,13 @@ group-reshapes align with the mesh device order (prototype-validated).
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
-from typing import Callable, NamedTuple
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..comm import group_sum   # reference reduction (shared with codecs)
 from ..configs.base import ArchConfig, ConsensusSpec, HsadmmConfig
@@ -55,6 +57,11 @@ class EngineSpec:
     # per-class collectives become independently schedulable, letting
     # early classes' payloads ship while later classes still compute.
     class_weights: bool = False
+    # Mesh whose ``data`` axis shards the worker dim W (set by the Engine
+    # when W's sharding is exactly ``data``).  Mosaic kernels cannot be
+    # partitioned by GSPMD, so local_step runs the Pallas prox update per
+    # data shard under shard_map (other mesh axes see whole leaves).
+    worker_mesh: Optional[Any] = None
 
     @property
     def sync_cfg(self) -> MaskSyncConfig:
@@ -339,9 +346,7 @@ def local_step(state: dict, batch, loss_fn: Callable, spec: EngineSpec,
             r = bcast_rho(get_leaf(rho1, key), th,
                           spec.stack_ndims(key), offset=1)
         mm = get_leaf(state["mom"], key) if spec.use_momentum else None
-        from ..kernels.ops import prox_sgd_update
-        return prox_sgd_update(th, gg, zz, uu, mm, r, eta,
-                               momentum=spec.momentum)
+        return _prox_update(spec, th, gg, zz, uu, mm, r, eta)
 
     new_theta, new_mom = {}, {}
     for key in leaf_keys(theta):
@@ -354,6 +359,26 @@ def local_step(state: dict, batch, loss_fn: Callable, spec: EngineSpec,
     if spec.use_momentum:
         out["mom"] = _unflatten(new_mom)
     return out, jnp.mean(losses)
+
+
+def _prox_update(spec: EngineSpec, theta, g, z, u, mom, rho, eta):
+    """``kernels.ops.prox_sgd_update`` on one (W, ...) leaf — per data
+    shard of the worker dim when ``spec.worker_mesh`` is set."""
+    from ..kernels.ops import prox_sgd_update
+    upd = functools.partial(prox_sgd_update, momentum=spec.momentum)
+    if spec.worker_mesh is None:
+        return upd(theta, g, z, u, mom, rho, eta)
+    ops_ = (theta, g, z, u, mom, rho)
+    # operands with the worker dim split over data; rho (1, stack, 1..)
+    # and eta are the same on every shard
+    specs = tuple(None if a is None else
+                  P("data") if a.ndim and a.shape[0] == theta.shape[0]
+                  else P() for a in ops_)
+    return jax.shard_map(
+        lambda a, e: upd(*a, e), mesh=spec.worker_mesh,
+        in_specs=(specs, P()),
+        out_specs=(P("data"), None if mom is None else P("data")),
+        check_vma=False)(ops_, eta)
 
 
 def _unflatten(flat: dict) -> dict:

@@ -38,7 +38,6 @@ import os
 import queue
 import shutil
 import threading
-import traceback
 from typing import Any, Optional
 
 import jax
@@ -117,6 +116,8 @@ def _write(ckpt_dir: str, arrays: dict[str, np.ndarray], meta: dict,
 _queue: "queue.Queue[tuple]" = queue.Queue()
 _worker_lock = threading.Lock()
 _worker: Optional[threading.Thread] = None
+# first failed background save since the last flush(); flush() raises it
+_failed: list = []
 
 
 def _drain() -> None:
@@ -124,8 +125,9 @@ def _drain() -> None:
         item = _queue.get()
         try:
             _write(*item)
-        except Exception:   # never kill the writer; surface and carry on
-            traceback.print_exc()
+        except Exception as e:   # keep the writer alive for later saves
+            if not _failed:
+                _failed.append(e)
         finally:
             _queue.task_done()
 
@@ -140,8 +142,11 @@ def _ensure_worker() -> None:
 
 
 def flush() -> None:
-    """Block until every queued background save has been published."""
+    """Block until every queued background save has been published.
+    Raises the first background save that failed since the last flush."""
     _queue.join()
+    if _failed:
+        raise _failed.pop()
 
 
 # ---------------------------------------------------------------------------

@@ -57,6 +57,9 @@ class Collective:
     channel_id: Optional[int]
     computation: str
     trips: int = 1            # trip-count weight (see hlo_cost)
+    # bytes of each operand tensor: XLA's combiners merge independent
+    # collectives into one instruction carrying several tensors
+    tensor_bytes: tuple = ()
     replica_groups: list = field(default_factory=list, repr=False)
 
     @property
@@ -134,6 +137,29 @@ def split_op(line: str) -> Optional[tuple[str, str, str, str]]:
     operands = rest[p + 1:end - 1]
     attrs = rest[end:]
     return result_type, kind, operands, attrs
+
+
+_NAME_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
+_REF_RE = re.compile(r"%([\w.\-]+)")
+
+
+def split_ops(lines: list[str]):
+    """:func:`split_op` over one computation's instruction lines.  Newer
+    XLA prints operands as bare ``%name`` references; those get the type
+    of their defining instruction, so operand byte/shape parsing sees the
+    same ``dtype[dims] %name`` text either way."""
+    parsed = [(line, split_op(line)) for line in lines]
+    types = {_NAME_RE.match(line).group(1): p[0]
+             for line, p in parsed if p is not None}
+    for _, p in parsed:
+        if p is None:
+            continue
+        result_type, kind, operands, attrs = p
+        if not _SHAPE_RE.search(operands):
+            operands = _REF_RE.sub(
+                lambda m: f"{types.get(m.group(1), '')} %{m.group(1)}",
+                operands)
+        yield result_type, kind, operands, attrs
 
 
 _COMP_RE = re.compile(r"^\s*(ENTRY\s+)?%?([\w.\-]+)\s+\(.*\)\s*->.*\{$")
@@ -220,11 +246,7 @@ def collective_stats(txt: str, *, model: int = 1, data: int = 1,
     comps, _ = parse_computations(txt)
     out: list[Collective] = []
     for cname, lines in comps.items():
-        for line in lines:
-            parsed = split_op(line)
-            if parsed is None:
-                continue
-            result_type, kind, operands, attrs = parsed
+        for result_type, kind, operands, attrs in split_ops(lines):
             base = kind[:-6] if kind.endswith("-start") else kind
             if base not in _KINDS or kind.endswith("-done"):
                 continue
@@ -245,7 +267,10 @@ def collective_stats(txt: str, *, model: int = 1, data: int = 1,
                 wire_bytes=_wire_bytes(base, gsize, operand_b, result_b),
                 group_size=gsize, n_groups=len(groups), axis=axis,
                 fabric=fabric, channel_id=int(cid.group(1)) if cid else None,
-                computation=cname, replica_groups=groups))
+                computation=cname, replica_groups=groups,
+                tensor_bytes=tuple(shape_bytes(f"{t}[{d}]") for t, d in
+                                   _SHAPE_RE.findall(operands)
+                                   if t in _DTYPE_BYTES)))
     return out
 
 

@@ -23,7 +23,7 @@ import re
 from dataclasses import dataclass, field
 
 from .hlo import (Collective, collective_stats, parse_computations,
-                  shape_bytes, split_op)
+                  shape_bytes, split_ops)
 
 _TRIP_RE = re.compile(r'known_trip_count[":{\s]+n["\s:]+(\d+)')
 _CALL_ATTR_RE = re.compile(
@@ -104,11 +104,7 @@ def _op_flops(kind: str, result_type: str, operands: str, attrs: str) -> float:
 
 def _comp_costs(lines: list[str]) -> tuple[float, float]:
     flops = byts = 0.0
-    for line in lines:
-        parsed = split_op(line)
-        if parsed is None:
-            continue
-        result_type, kind, operands, attrs = parsed
+    for result_type, kind, operands, attrs in split_ops(lines):
         flops += _op_flops(kind, result_type, operands, attrs)
         if kind not in _SKIP_BYTES:
             byts += shape_bytes(result_type) + shape_bytes(operands)

@@ -9,7 +9,8 @@ without failing any correctness test.  This module gives the test suite
   * :func:`compile_count` — a context manager counting XLA *backend
     compilations* via ``jax.monitoring`` duration events (one
     ``/jax/core/compile/backend_compile_duration`` event per executable
-    built, including AOT ``.compile()`` calls);
+    built, including AOT ``.compile()`` calls), their seconds, and the
+    executables loaded from the persistent compilation cache instead;
   * :func:`counting` — wraps any callable (e.g. an engine's jitted round
     fn) with an invocation counter, for asserting dispatches-per-round.
 
@@ -25,39 +26,53 @@ from dataclasses import dataclass, field
 import jax
 
 _EVENT = "/jax/core/compile/backend_compile_duration"
-_totals = {"compiles": 0}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_totals = {"compiles": 0, "seconds": 0.0, "cache_hits": 0}
 _installed = False
 
 
 def _on_duration(name: str, duration: float, **kw) -> None:
     if name == _EVENT:
         _totals["compiles"] += 1
+        _totals["seconds"] += duration
+
+
+def _on_event(name: str, **kw) -> None:
+    if name == _CACHE_HIT:
+        _totals["cache_hits"] += 1
 
 
 def _ensure_listener() -> None:
     global _installed
     if not _installed:
         jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        jax.monitoring.register_event_listener(_on_event)
         _installed = True
 
 
 @dataclass
 class CompileStats:
     compiles: int = 0
+    seconds: float = 0.0      # backend compile time of those builds
+    cache_hits: int = 0       # executables read from the persistent cache
 
 
 @contextlib.contextmanager
 def compile_count():
     """``with compile_count() as stats: ...`` — afterwards,
     ``stats.compiles`` is the number of XLA executables built inside the
-    block (jit cache hits and op-by-op dispatches count zero)."""
+    block (jit cache hits and op-by-op dispatches count zero),
+    ``stats.seconds`` their compile time and ``stats.cache_hits`` the
+    executables the persistent compilation cache supplied."""
     _ensure_listener()
-    start = _totals["compiles"]
+    start = dict(_totals)
     stats = CompileStats()
     try:
         yield stats
     finally:
-        stats.compiles = _totals["compiles"] - start
+        stats.compiles = _totals["compiles"] - start["compiles"]
+        stats.seconds = _totals["seconds"] - start["seconds"]
+        stats.cache_hits = _totals["cache_hits"] - start["cache_hits"]
 
 
 def probe_seconds(fn, *args, reps: int = 3, warmup: int = 1

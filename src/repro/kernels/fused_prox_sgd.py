@@ -28,13 +28,14 @@ def _kernel(theta_ref, g_ref, z_ref, u_ref, mom_ref, out_t_ref, out_m_ref,
 
 
 def _blocks(R, C, block_r, block_c):
-    br = min(block_r, R)
-    while R % br:
-        br -= 1
-    bc = min(block_c, C)
-    while C % bc:
-        bc -= 1
-    return br, bc
+    """Tile + padded grid.  Mosaic takes a block dim that is the whole
+    array dim or a multiple of (8, 128); a non-dividing final block reads
+    pad and its out-of-range writes are dropped (elementwise, so the pad
+    never reaches a kept element)."""
+    assert block_r % 8 == 0 and block_c % 128 == 0, (block_r, block_c)
+    br = R if R <= block_r else block_r
+    bc = C if C <= block_c else block_c
+    return (br, bc), (pl.cdiv(R, br), pl.cdiv(C, bc))
 
 
 def fused_prox_sgd(theta, g, z, u, mom, *, eta, rho, momentum,
@@ -46,8 +47,7 @@ def fused_prox_sgd(theta, g, z, u, mom, *, eta, rho, momentum,
     uses :func:`fused_prox_sgd_dyn` instead.
     """
     R, C = theta.shape
-    br, bc = _blocks(R, C, block_r, block_c)
-    grid = (R // br, C // bc)
+    (br, bc), grid = _blocks(R, C, block_r, block_c)
     bs = pl.BlockSpec((br, bc), lambda i, j: (i, j))
     return pl.pallas_call(
         functools.partial(_kernel, eta=eta, rho=rho, momentum=momentum),
@@ -78,8 +78,7 @@ def fused_prox_sgd_dyn(theta, g, z, u, mom, rho_col, eta, *, momentum,
     tensors; rho/eta tiles are negligible extra traffic.
     """
     R, C = theta.shape
-    br, bc = _blocks(R, C, block_r, block_c)
-    grid = (R // br, C // bc)
+    (br, bc), grid = _blocks(R, C, block_r, block_c)
     bs = pl.BlockSpec((br, bc), lambda i, j: (i, j))
     rs = pl.BlockSpec((br, 1), lambda i, j: (i, 0))
     es = pl.BlockSpec((1, 1), lambda i, j: (0, 0))

@@ -1,8 +1,12 @@
 """Jitted public wrappers for the Pallas kernels.
 
-On the TPU target the kernels compile natively; on this CPU container they
-run in interpret mode (the kernel body executes as traced JAX) — the tests
-assert bit-level agreement with the ref.py oracles either way.
+Every shim has one path.  A shim backed by a kernel runs it natively on
+the TPU and in interpret mode (the kernel body executed as traced JAX)
+everywhere else — ``_interpret`` decides nothing but that, and a kernel
+that fails to lower on the TPU raises.  The tests assert agreement with
+the ``ref.py`` oracles.  Lane gathers (the compact pack/expand and the
+compact wire encodes/decodes) and dequantization have no kernel: they
+call the XLA op on every backend.
 """
 from __future__ import annotations
 
@@ -13,17 +17,11 @@ import jax
 import jax.numpy as jnp
 
 from . import ref as _ref
-from .compact import gather_groups as _gather
 from .fused_prox_sgd import fused_prox_sgd as _fused
 from .fused_prox_sgd import fused_prox_sgd_dyn as _fused_dyn
 from .group_norms import group_norms_sq as _gnorms
-from .ssd_scan import ssd_chunk_scan as _ssd
-from .wire import gather_dequantize as _w_gdq
-from .wire import gather_quantize as _w_gq
-from .wire import gather_quantize_q4 as _w_gq4
 from .wire import quantize_pack_q4 as _w_q4
 from .wire import quantize_rows as _w_quant
-from .wire import unpack_gather_dequantize_q4 as _w_udq4
 
 
 def _interpret() -> bool:
@@ -96,45 +94,34 @@ def prox_sgd_update(theta, g, z, u, mom, rho, eta, *, momentum=0.9):
     return theta - e * gtot, None
 
 
+def _inverse_index(idx, full: int, fill: int):
+    """(full,) int32: position of each channel in the kept list ``idx``,
+    ``fill`` for dropped channels (zero-fill expansion as a gather into a
+    zero-padded buffer, paper §4.4.3 — scatter hardware is never needed)."""
+    return jnp.full((full,), fill, jnp.int32).at[idx].set(
+        jnp.arange(idx.shape[0], dtype=jnp.int32))
+
+
 @jax.jit
 def compact_groups(x, idx):
     """Pack kept groups: x (..., C, K) gathered along axis -2 by idx (B,)."""
-    shape = x.shape
-    x2 = jnp.moveaxis(x, -2, -1).reshape(-1, shape[-2])
-    out = _gather(x2, idx, interpret=_interpret())
-    out = out.reshape(shape[:-2] + (shape[-1], idx.shape[0]))
-    return jnp.moveaxis(out, -1, -2)
+    return jnp.take(x, idx, axis=-2)
 
 
 @functools.partial(jax.jit, static_argnames=("full",))
 def expand_groups(c, idx, full: int):
     """Zero-fill recovery via inverse-permutation gather (paper §4.4.3)."""
-    B = idx.shape[0]
-    inv = jnp.full((full,), B, jnp.int32).at[idx].set(
-        jnp.arange(B, dtype=jnp.int32))
-    shape = c.shape
-    c2 = jnp.moveaxis(c, -2, -1).reshape(-1, shape[-2])
-    c2 = jnp.pad(c2, ((0, 0), (0, 1)))
-    out = _gather(c2, inv, interpret=_interpret())
-    out = out.reshape(shape[:-2] + (shape[-1], full))
-    return jnp.moveaxis(out, -1, -2)
+    inv = _inverse_index(idx, full, idx.shape[0])
+    pad = [(0, 0)] * c.ndim
+    pad[-2] = (0, 1)
+    return jnp.take(jnp.pad(c, pad), inv, axis=-2)
 
 
 # ------------------------------------------------------------------ #
-# fused wire path (kernels/wire.py): the repro.comm codecs' element
-# formats as single streaming passes.  Scale granularity is one f32 per
-# row of the (R, C) 2-D view — a function of the leaf SHAPE, never of
-# the kernel block size, so wire_bytes stays analytic.
-#
-# Backend routing: on compiled-Pallas backends the shims call the fused
-# kernels; under interpretation (CPU) they call the pure-jnp references
-# from kernels/ref.py instead.  Interpret mode is the conformance
-# vehicle (tests/test_kernels.py drives it explicitly), not a perf
-# contract — production executables should not trace through the Pallas
-# interpreter, whose lowering pins wall time and compile behavior to
-# interpreter internals.  The references are bit-identical by test
-# contract and compile to plain XLA; measured in-context the two routes
-# are a wall-time wash on CPU (benchmarks/run.py wire rows).
+# wire path (kernels/wire.py): the repro.comm codecs' element formats.
+# Scale granularity is one f32 per row of the (R, C) 2-D view — a
+# function of the leaf SHAPE, never of the kernel block size, so
+# wire_bytes stays analytic.
 # ------------------------------------------------------------------ #
 
 
@@ -149,47 +136,30 @@ def quantize_rows(x, levels=127):
     (q int8 like x, scale f32 broadcastable against x)."""
     shape = x.shape
     R, C = _rc(shape)
-    x2 = x.reshape(R, C)
-    q, s = (_ref.quantize_rows_ref(x2, levels) if _interpret() else
-            _w_quant(x2, levels=levels, interpret=False))
+    q, s = _w_quant(x.reshape(R, C), levels=levels, interpret=_interpret())
     return q.reshape(shape), s.reshape(_scale_shape(shape))
 
 
 @jax.jit
 def dequantize_rows(q, scale):
     """Inverse of :func:`quantize_rows` (f32 out, caller casts)."""
-    shape = q.shape
-    R, C = _rc(shape)
-    if _interpret():
-        out = q.reshape(R, C).astype(jnp.float32) * scale.reshape(R, 1)
-    else:
-        out = _w_gdq(q.reshape(R, C), scale.reshape(R, 1),
-                     jnp.arange(C, dtype=jnp.int32), interpret=False)
-    return out.reshape(shape)
+    return q.astype(jnp.float32) * scale.astype(jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("levels",))
 def gather_quantize(x, idx, levels=127):
-    """x (R, C), idx (B,): fused kept-group gather + per-row quantize —
-    the compact+q8 encode as ONE pass -> (q int8 (R, B), scale (R, 1))."""
-    idx = idx.astype(jnp.int32)
-    if _interpret():
-        return _ref.gather_quantize_ref(x, idx, levels)
-    return _w_gq(x, idx, levels=levels, interpret=False)
+    """x (R, C), idx (B,): kept-group gather + per-row quantize — the
+    compact+q8 encode -> (q int8 (R, B), scale (R, 1))."""
+    return _ref.gather_quantize_ref(x, idx.astype(jnp.int32), levels)
 
 
 @functools.partial(jax.jit, static_argnames=("full",))
 def scatter_dequantize(q, scale, idx, full: int):
-    """Fused dequantize + zero-fill expansion: q (R, B) int8 of the kept
-    channels ``idx`` -> f32 (R, full), zeros on the dropped channels
-    (inverse-permutation gather into a zero-padded buffer, §4.4.3)."""
-    B = idx.shape[0]
-    inv = jnp.full((full,), B, jnp.int32).at[idx].set(
-        jnp.arange(B, dtype=jnp.int32))
-    qp = jnp.pad(q, ((0, 0), (0, 1)))
-    if _interpret():
-        return _ref.gather_dequantize_ref(qp, scale.reshape(-1, 1), inv)
-    return _w_gdq(qp, scale.reshape(-1, 1), inv, interpret=False)
+    """Dequantize + zero-fill expansion: q (R, B) int8 of the kept
+    channels ``idx`` -> f32 (R, full), zeros on the dropped channels."""
+    inv = _inverse_index(idx, full, idx.shape[0])
+    return _ref.gather_dequantize_ref(jnp.pad(q, ((0, 0), (0, 1))),
+                                      scale.reshape(-1, 1), inv)
 
 
 @jax.jit
@@ -199,9 +169,7 @@ def quantize_pack_q4(x):
     scale f32).  Odd minor dims carry one zero pad nibble."""
     shape = x.shape
     R, C = _rc(shape)
-    x2 = x.reshape(R, C)
-    p, s = (_ref.quantize_pack_q4_ref(x2) if _interpret() else
-            _w_q4(x2, interpret=False))
+    p, s = _w_q4(x.reshape(R, C), interpret=_interpret())
     p_shape = (shape[:-1] if len(shape) >= 1 else ()) + ((C + 1) // 2,)
     return p.reshape(p_shape), s.reshape(_scale_shape(shape))
 
@@ -213,58 +181,36 @@ def unpack_dequantize_q4(p, scale, n: int):
     shape = p.shape
     Cp = shape[-1] if shape else 1
     R = max(math.prod(shape[:-1]), 1) if len(shape) >= 2 else 1
-    if _interpret():
-        q = _ref.unpack_q4_ref(p.reshape(R, Cp), n)
-        out = q.astype(jnp.float32) * scale.reshape(R, 1)
-    else:
-        out = _w_udq4(p.reshape(R, Cp), scale.reshape(R, 1),
-                      jnp.arange(n, dtype=jnp.int32), interpret=False)
+    q = _ref.unpack_q4_ref(p.reshape(R, Cp), n)
+    out = q.astype(jnp.float32) * scale.reshape(R, 1)
     return out.reshape((shape[:-1] if len(shape) >= 2 else ()) + (n,))
 
 
 @jax.jit
 def gather_quantize_q4(x, idx):
-    """x (R, C), idx (B,): gather + q4 quantize + nibble pack, one pass
-    -> (packed uint8 (R, ceil(B/2)), scale (R, 1))."""
-    idx = idx.astype(jnp.int32)
-    if _interpret():
-        return _ref.quantize_pack_q4_ref(jnp.take(x, idx, axis=1))
-    return _w_gq4(x, idx, interpret=False)
+    """x (R, C), idx (B,): gather + q4 quantize + nibble pack ->
+    (packed uint8 (R, ceil(B/2)), scale (R, 1))."""
+    return _ref.quantize_pack_q4_ref(jnp.take(x, idx.astype(jnp.int32),
+                                              axis=1))
 
 
 @functools.partial(jax.jit, static_argnames=("full",))
 def scatter_dequantize_q4(p, scale, idx, full: int):
-    """Fused q4 unpack + dequantize + zero-fill expansion -> (R, full).
-    The packed buffer gains one zero byte column; dropped channels index
-    its (always-zero) nibbles."""
-    R, Cp = p.shape
+    """q4 unpack + dequantize + zero-fill expansion -> (R, full)."""
+    R, _ = p.shape
     B = idx.shape[0]
-    if _interpret():
-        dec = (_ref.unpack_q4_ref(p, B).astype(jnp.float32)
-               * scale.reshape(R, 1))
-        inv = jnp.full((full,), B, jnp.int32).at[idx].set(
-            jnp.arange(B, dtype=jnp.int32))
-        return jnp.take(jnp.pad(dec, ((0, 0), (0, 1))), inv, axis=1)
-    inv = jnp.full((full,), 2 * Cp, jnp.int32).at[idx].set(
-        jnp.arange(B, dtype=jnp.int32))
-    return _w_udq4(jnp.pad(p, ((0, 0), (0, 1))), scale.reshape(R, 1), inv,
-                   interpret=False)
+    dec = _ref.unpack_q4_ref(p, B).astype(jnp.float32) * scale.reshape(R, 1)
+    inv = _inverse_index(idx, full, B)
+    return jnp.take(jnp.pad(dec, ((0, 0), (0, 1))), inv, axis=1)
 
 
 @jax.jit
 def gather_rows(x, idx):
-    """Plain 2-D kept-gather: x (R, C), idx (B,) -> (R, B) (the stock
-    two-pass encode path gathers with this, then quantizes)."""
-    return _gather(x, idx.astype(jnp.int32), interpret=_interpret())
+    """Plain 2-D kept-gather: x (R, C), idx (B,) -> (R, B)."""
+    return jnp.take(x, idx.astype(jnp.int32), axis=1)
 
 
 @jax.jit
 def group_norms_sq(x):
     """(G, C, K) -> (G, C) squared group norms."""
     return _gnorms(x, interpret=_interpret())
-
-
-@functools.partial(jax.jit, static_argnames=("chunk", "block_h"))
-def ssd_chunk_scan(x, dt, A, Bm, Cm, chunk=128, block_h=8):
-    return _ssd(x, dt, A, Bm, Cm, chunk=chunk, block_h=block_h,
-                interpret=_interpret())

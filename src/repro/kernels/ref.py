@@ -15,13 +15,6 @@ def fused_prox_sgd_ref(theta, g, z, u, mom, *, eta, rho, momentum):
     return theta - eta * mom_new, mom_new
 
 
-def gather_groups_ref(x, idx):
-    """x: (R, C), idx: (B,) -> (R, B) — the §4.4 packing gather (compaction
-    along the group axis; expansion reuses it with an inverse index into a
-    zero-padded buffer)."""
-    return jnp.take(x, idx, axis=1)
-
-
 def quantize_rows_ref(x, levels=127):
     """x: (R, C) -> (q int8, scale f32 (R, 1)) per-row symmetric
     quantization (the wire.py scale-granularity contract)."""
@@ -71,9 +64,3 @@ def group_norms_ref(x):
     fan-in axis (mask scores, paper §2.1)."""
     return jnp.sum(jnp.square(x.astype(jnp.float32)), axis=-1)
 
-
-def ssd_chunk_scan_ref(x, dt, A, Bm, Cm, chunk):
-    """Mamba2 SSD chunked scan (models.ssm.ssd_scan is the system impl and
-    oracle; re-exported here so kernel tests depend only on kernels/)."""
-    from ..models.ssm import ssd_scan
-    return ssd_scan(x, dt, A, Bm, Cm, chunk)

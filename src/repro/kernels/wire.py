@@ -1,15 +1,12 @@
 """Fused wire-path kernels: one streaming pass per payload leaf.
 
-Encoding a consensus payload used to cost three XLA passes over each
-leaf — gather the kept groups (kernels/compact.py), reduce the abs-max,
-then scale/round/cast — memory traffic the paper calls "inherently
-memory-bandwidth bound".  These kernels collapse the encode into ONE
-pass: each (block_r, C) row block is loaded into VMEM once, reduced to
-its per-row abs-max, and written back quantized (optionally gathered
-and/or nibble-packed on the way out).  Decode is the mirrored single
-pass: unpack + dequantize + zero-fill expansion via an
-inverse-permutation gather into a zero-padded compact buffer, so scatter
-hardware is never needed (same trick as ops.expand_groups).
+Encoding a consensus payload as separate XLA ops costs a pass to reduce
+the per-row abs-max and another to scale/round/cast (and, for q4, to
+pack).  These kernels collapse the encode into ONE pass: each
+(block_r, C) row block is loaded into VMEM once, reduced to its per-row
+abs-max, and written back quantized (q4: nibble-packed on the way out).
+Decode is a per-row multiply that XLA fuses into whatever consumes it
+(``kernels.ops``), so it has no kernel.
 
 Scale granularity is one f32 scale per ROW of the (R, C) 2-D view —
 deterministic in the leaf shape, NOT in the tunable kernel block size,
@@ -18,7 +15,12 @@ stable however the kernel is tiled (DESIGN.md "Per-row wire scales").
 
 The q4 format packs two channels per byte along the minor axis (odd
 minor dims carry one zero pad nibble); nibbles are two's-complement
-4-bit in [-7, 7], sign-extended on decode as ``(n ^ 8) - 8``.
+4-bit in [-7, 7], sign-extended on decode as ``(n ^ 8) - 8``.  Mosaic
+cannot interleave lanes (no lane-strided loads, no minor-dim reshape),
+so the pack runs on the MXU: byte j = n[2j] + 16 * n[2j+1] is a product
+with a constant 0/1/16 matrix — exact at any matmul precision, since
+nibbles in [0, 15], the weights and every sum (at most 255) are exact in
+bf16.
 
 Grids pad with ``pl.cdiv``: a non-dividing final row block reads
 garbage pad rows whose outputs fall outside the logical shape and are
@@ -32,20 +34,31 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+# VMEM bytes one f32 row block may take: the block is double-buffered
+# next to its output, inside v5e's 16 MiB default scoped VMEM
+_BLOCK_BYTES = 2 << 20
 
-def _row_grid(R: int, block_r: int) -> tuple[int, tuple[int]]:
-    br = min(block_r, R)
+
+def _row_grid(R: int, C: int, block_r: int) -> tuple[int, tuple[int]]:
+    """Row block (a multiple of 8, or all of R) + its padded grid."""
+    fit = max(8, (_BLOCK_BYTES // (4 * max(C, 1))) // 8 * 8)
+    br = min(block_r, fit)
+    br = R if R <= br else br
     return br, (pl.cdiv(R, br),)
 
 
+def _row_scale(x, levels):
+    return jnp.max(jnp.abs(x), axis=1, keepdims=True) / levels + 1e-30
+
+
 # ---------------------------------------------------------------------------
-# int8: quantize / gather+quantize / gather+dequantize
+# int8
 # ---------------------------------------------------------------------------
 
 
 def _quant_kernel(x_ref, q_ref, s_ref, *, levels):
     x = x_ref[...].astype(jnp.float32)
-    s = jnp.max(jnp.abs(x), axis=1, keepdims=True) / levels + 1e-30
+    s = _row_scale(x, levels)
     q_ref[...] = jnp.clip(jnp.round(x / s), -levels, levels).astype(jnp.int8)
     s_ref[...] = s
 
@@ -54,7 +67,7 @@ def quantize_rows(x, *, levels=127, block_r=256, interpret=False):
     """x: (R, C) -> (q int8 (R, C), scale f32 (R, 1)): per-row abs-max +
     quantize in one pass over the block in VMEM."""
     R, C = x.shape
-    br, grid = _row_grid(R, block_r)
+    br, grid = _row_grid(R, C, block_r)
     return pl.pallas_call(
         functools.partial(_quant_kernel, levels=levels),
         out_shape=(jax.ShapeDtypeStruct((R, C), jnp.int8),
@@ -67,87 +80,50 @@ def quantize_rows(x, *, levels=127, block_r=256, interpret=False):
     )(x)
 
 
-def _gq_kernel(idx_ref, x_ref, q_ref, s_ref, *, levels):
-    g = jnp.take(x_ref[...], idx_ref[...], axis=1).astype(jnp.float32)
-    s = jnp.max(jnp.abs(g), axis=1, keepdims=True) / levels + 1e-30
-    q_ref[...] = jnp.clip(jnp.round(g / s), -levels, levels).astype(jnp.int8)
-    s_ref[...] = s
-
-
-def gather_quantize(x, idx, *, levels=127, block_r=256, interpret=False):
-    """x: (R, C), idx: (B,) -> (q int8 (R, B), scale f32 (R, 1)): the
-    §4.4 kept-group gather fused with symmetric-int8 quantization — the
-    compact+q8 encode as ONE streaming pass instead of three."""
-    R, C = x.shape
-    B = idx.shape[0]
-    br, grid = _row_grid(R, block_r)
-    return pl.pallas_call(
-        functools.partial(_gq_kernel, levels=levels),
-        out_shape=(jax.ShapeDtypeStruct((R, B), jnp.int8),
-                   jax.ShapeDtypeStruct((R, 1), jnp.float32)),
-        grid=grid,
-        in_specs=[pl.BlockSpec((B,), lambda i: (0,)),
-                  pl.BlockSpec((br, C), lambda i: (i, 0))],
-        out_specs=(pl.BlockSpec((br, B), lambda i: (i, 0)),
-                   pl.BlockSpec((br, 1), lambda i: (i, 0))),
-        interpret=interpret,
-    )(idx, x)
-
-
-def _gdq_kernel(idx_ref, q_ref, s_ref, out_ref):
-    g = jnp.take(q_ref[...], idx_ref[...], axis=1).astype(jnp.float32)
-    out_ref[...] = (g * s_ref[...]).astype(out_ref.dtype)
-
-
-def gather_dequantize(q, s, idx, *, out_dtype=jnp.float32, block_r=256,
-                      interpret=False):
-    """q: (R, B) int8, s: (R, 1), idx: (Cout,) columns of q -> f32-ish
-    (R, Cout).  With ``idx = arange(B)`` this is the plain dequantize;
-    with an inverse-permutation index into a zero-padded q it is the
-    fused dequantize + zero-fill expansion (decode of compact+q8)."""
-    R, _ = q.shape
-    Cout = idx.shape[0]
-    br, grid = _row_grid(R, block_r)
-    return pl.pallas_call(
-        _gdq_kernel,
-        out_shape=jax.ShapeDtypeStruct((R, Cout), out_dtype),
-        grid=grid,
-        in_specs=[pl.BlockSpec((Cout,), lambda i: (0,)),
-                  pl.BlockSpec((br, q.shape[1]), lambda i: (i, 0)),
-                  pl.BlockSpec((br, 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((br, Cout), lambda i: (i, 0)),
-        interpret=interpret,
-    )(idx, q, s)
-
-
 # ---------------------------------------------------------------------------
-# q4: two channels per byte, pack/unpack in-kernel
+# q4: quantize + pack two channels per byte
 # ---------------------------------------------------------------------------
 
-
-def _pack_nibbles(q):
-    """(br, n) int32 nibbles in [0, 15] -> (br, ceil(n/2)) uint8."""
-    if q.shape[1] % 2:
-        q = jnp.pad(q, ((0, 0), (0, 1)))
-    q = q.reshape(q.shape[0], -1, 2)
-    return (q[..., 0] | (q[..., 1] << 4)).astype(jnp.uint8)
+_PACK_IN = 256    # unpacked lanes per MXU pack step (-> 128 packed lanes)
 
 
-def _unpack_nibbles(p):
-    """(br, Cp) uint8 -> (br, 2*Cp) int32, sign-extended from 4 bits."""
-    p = p.astype(jnp.int32)
-    lo = p & 0xF
-    hi = (p >> 4) & 0xF
-    q = jnp.stack([lo, hi], axis=-1).reshape(p.shape[0], -1)
-    return (q ^ 8) - 8
+def _pack_matrix(n_in: int, n_out: int):
+    """(n_in, n_out): column j takes nibble 2j at weight 1 and nibble
+    2j+1 at weight 16."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (n_in, n_out), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (n_in, n_out), 1)
+    w = jnp.where(r % 2 == 0, 1.0, 16.0)
+    return jnp.where(r // 2 == c, w, 0.0)
 
 
 def _q4_quant_kernel(x_ref, p_ref, s_ref):
-    x = x_ref[...].astype(jnp.float32)
-    s = jnp.max(jnp.abs(x), axis=1, keepdims=True) / 7.0 + 1e-30
-    q = jnp.clip(jnp.round(x / s), -7, 7).astype(jnp.int32) & 0xF
-    p_ref[...] = _pack_nibbles(q)
+    s = _row_scale(x_ref[...].astype(jnp.float32), 7.0)
     s_ref[...] = s
+
+    def pack(lo, lo_out, n_in, n_out, mat):
+        x = x_ref[:, pl.ds(lo, n_in)].astype(jnp.float32)
+        q = jnp.clip(jnp.round(x / s), -7, 7).astype(jnp.int32) & 0xF
+        b = jnp.dot(q.astype(jnp.float32), mat,
+                    preferred_element_type=jnp.float32)
+        p_ref[:, pl.ds(lo_out, n_out)] = b.astype(jnp.int32) \
+            .astype(jnp.uint8)
+
+    C = x_ref.shape[1]
+    full = C // _PACK_IN
+    if full:
+        mat = _pack_matrix(_PACK_IN, _PACK_IN // 2)
+
+        def body(j, carry):
+            half = _PACK_IN // 2
+            pack(pl.multiple_of(j * _PACK_IN, _PACK_IN),
+                 pl.multiple_of(j * half, half), _PACK_IN, half, mat)
+            return carry
+        jax.lax.fori_loop(0, full, body, 0)
+    tail = C - full * _PACK_IN
+    if tail:
+        n_out = (tail + 1) // 2
+        pack(full * _PACK_IN, full * _PACK_IN // 2, tail, n_out,
+             _pack_matrix(tail, n_out))
 
 
 def quantize_pack_q4(x, *, block_r=256, interpret=False):
@@ -155,7 +131,7 @@ def quantize_pack_q4(x, *, block_r=256, interpret=False):
     per-row abs-max, quantize to [-7, 7], and nibble-pack in one pass."""
     R, C = x.shape
     Cp = (C + 1) // 2
-    br, grid = _row_grid(R, block_r)
+    br, grid = _row_grid(R, C, block_r)
     return pl.pallas_call(
         _q4_quant_kernel,
         out_shape=(jax.ShapeDtypeStruct((R, Cp), jnp.uint8),
@@ -166,58 +142,3 @@ def quantize_pack_q4(x, *, block_r=256, interpret=False):
                    pl.BlockSpec((br, 1), lambda i: (i, 0))),
         interpret=interpret,
     )(x)
-
-
-def _gq4_kernel(idx_ref, x_ref, p_ref, s_ref):
-    g = jnp.take(x_ref[...], idx_ref[...], axis=1).astype(jnp.float32)
-    s = jnp.max(jnp.abs(g), axis=1, keepdims=True) / 7.0 + 1e-30
-    q = jnp.clip(jnp.round(g / s), -7, 7).astype(jnp.int32) & 0xF
-    p_ref[...] = _pack_nibbles(q)
-    s_ref[...] = s
-
-
-def gather_quantize_q4(x, idx, *, block_r=256, interpret=False):
-    """x: (R, C), idx: (B,) -> (packed uint8 (R, ceil(B/2)), scale
-    (R, 1)): kept-group gather + q4 quantize + nibble pack, one pass."""
-    R, C = x.shape
-    B = idx.shape[0]
-    Bp = (B + 1) // 2
-    br, grid = _row_grid(R, block_r)
-    return pl.pallas_call(
-        _gq4_kernel,
-        out_shape=(jax.ShapeDtypeStruct((R, Bp), jnp.uint8),
-                   jax.ShapeDtypeStruct((R, 1), jnp.float32)),
-        grid=grid,
-        in_specs=[pl.BlockSpec((B,), lambda i: (0,)),
-                  pl.BlockSpec((br, C), lambda i: (i, 0))],
-        out_specs=(pl.BlockSpec((br, Bp), lambda i: (i, 0)),
-                   pl.BlockSpec((br, 1), lambda i: (i, 0))),
-        interpret=interpret,
-    )(idx, x)
-
-
-def _udq4_kernel(idx_ref, p_ref, s_ref, out_ref):
-    q = _unpack_nibbles(p_ref[...])
-    g = jnp.take(q, idx_ref[...], axis=1).astype(jnp.float32)
-    out_ref[...] = (g * s_ref[...]).astype(out_ref.dtype)
-
-
-def unpack_gather_dequantize_q4(p, s, idx, *, out_dtype=jnp.float32,
-                                block_r=256, interpret=False):
-    """p: (R, Cp) packed uint8, s: (R, 1), idx: (Cout,) indices into the
-    UNPACKED channel space [0, 2*Cp) -> (R, Cout).  ``idx = arange(n)``
-    trims the pad nibble (plain decode); an inverse-permutation index
-    into a zero-byte-padded p is the fused decode + zero-fill expand."""
-    R, Cp = p.shape
-    Cout = idx.shape[0]
-    br, grid = _row_grid(R, block_r)
-    return pl.pallas_call(
-        _udq4_kernel,
-        out_shape=jax.ShapeDtypeStruct((R, Cout), out_dtype),
-        grid=grid,
-        in_specs=[pl.BlockSpec((Cout,), lambda i: (0,)),
-                  pl.BlockSpec((br, Cp), lambda i: (i, 0)),
-                  pl.BlockSpec((br, 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((br, Cout), lambda i: (i, 0)),
-        interpret=interpret,
-    )(idx, p, s)

@@ -96,9 +96,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     rec = {"arch": arch, "shape": shape_name, "mesh": mesh_name,
            "kind": shape.kind, "frozen": frozen,
            "mask_mode": hp.mask_mode, "n_params": None}
-    # jax>=0.5 exposes jax.set_mesh; older versions use Mesh as the context
-    ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-    ctx.__enter__()
+    jax.set_mesh(mesh).__enter__()
 
     eng = Engine(bundle, mesh, shape)
     if not compact:

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .cache import setup_compile_cache
 from ..configs import get_config
 from ..configs.base import ConsensusSpec
 from ..core.shrinkage import compact_params
@@ -163,6 +164,7 @@ def main(argv=None):
                     help="nucleus sampling mass (only used when "
                          "--temperature > 0)")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     key = jax.random.PRNGKey(0)

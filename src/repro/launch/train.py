@@ -3,10 +3,10 @@
     PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \\
         --smoke --outer-iters 20 --batch 8 --seq 64 --workers 4
 
-On this CPU container the mesh is the locally visible devices; on a real
-deployment the same entry point runs under the production mesh (the
-engine/loop are mesh-agnostic).  ``--baseline ddp|topk`` runs the paper's
-comparison trainers instead of H-SADMM.
+The mesh is every locally visible device (``launch.mesh.make_host_mesh``):
+one TPU chip, a four-chip host, or the CPU in tests; the engine/loop are
+mesh-agnostic.  ``--baseline ddp|topk`` runs the paper's comparison
+trainers instead of H-SADMM.
 """
 from __future__ import annotations
 
@@ -15,6 +15,7 @@ import json
 
 import jax
 
+from .cache import setup_compile_cache
 from ..configs import SHAPES, get_config
 from ..configs.base import ConsensusSpec, ShapeConfig
 from ..models import build
@@ -99,6 +100,7 @@ def main(argv=None):
                          "analytic plan_bytes volumes")
     ap.add_argument("--report", default=None, help="write JSON report here")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     if args.from_json:
         import dataclasses

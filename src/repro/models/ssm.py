@@ -2,8 +2,8 @@
 
 Block: in-projections (z, x, B, C, dt) -> causal depthwise conv on (x,B,C)
 -> chunked SSD scan -> gated RMSNorm -> out-projection.  The SSD scan is
-the compute hot-spot; ``repro.kernels.ssd_scan`` provides the Pallas TPU
-kernel, this module holds the pure-jnp implementation (also its oracle).
+the compute hot-spot; this module holds its pure-jnp implementation (no
+Pallas kernel: Mosaic has no cumsum lowering).
 
 Serving keeps O(1) per-token state: (B,H,hd,N) SSM state + (B,K-1,conv)
 conv tail — this is why mamba2/jamba run the ``long_500k`` cell that pure
@@ -80,8 +80,7 @@ def ssd_scan(x, dtv, A, Bm, Cm, chunk, h0=None):
 
     Recurrence: h_t = exp(dt_t A) h_{t-1} + dt_t B_t x_t^T ;  y_t = C_t h_t.
     One scan over chunks carries the SSM state; per chunk the intra-chunk
-    part is a masked (Q,Q) attention-like product — the structure the Pallas
-    kernel tiles into VMEM (kernels/ssd_scan.py).
+    part is a masked (Q,Q) attention-like product.
     """
     Bsz, T, H, Pd = x.shape
     N = Bm.shape[-1]

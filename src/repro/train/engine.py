@@ -94,9 +94,12 @@ class Engine:
         self.axes = dict(zip(mesh.axis_names, mesh.devices.shape))
         self.consensus = consensus or make_consensus_spec(self.cfg, mesh)
         self.class_weights = class_weights
+        worker_mesh = mesh if self.axes.get("data", 1) > 1 \
+            and self._lead_spec(self.workers) == "data" else None
         self.spec = EngineSpec(
             plan=bundle.plan, consensus=self.consensus, hp=self.cfg.hsadmm,
-            stack_map=tuple(bundle.stack_map), class_weights=class_weights)
+            stack_map=tuple(bundle.stack_map), class_weights=class_weights,
+            worker_mesh=worker_mesh)
         self.shape = shape
         if self.cfg.hsadmm.staleness not in (0, 1):
             raise ValueError(

@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.dist import checkpoint as ckpt
 
@@ -27,6 +28,20 @@ def test_save_restore_roundtrip(tmp_path):
     assert meta["step"] == 7
     for a, b in zip(jax.tree.leaves(st), jax.tree.leaves(st2)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_failed_background_save_raises_from_flush(tmp_path):
+    """A background save that fails must fail the run at flush(), not
+    print and carry on; the writer stays alive for the saves after it."""
+    st = _state(4)
+    ckpt.save(str(tmp_path), st, {"step": 1, "bad": object()},
+              background=True)                  # meta.json cannot encode
+    ckpt.save(str(tmp_path), st, {"step": 2}, background=True)
+    with pytest.raises(TypeError):
+        ckpt.flush()
+    assert ckpt.latest(str(tmp_path)).endswith("ckpt_00000002")
+    assert sorted(p.name for p in tmp_path.iterdir()) == ["ckpt_00000002"]
+    ckpt.flush()                                # reported once
 
 
 def test_keep_policy(tmp_path):

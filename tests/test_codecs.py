@@ -373,6 +373,7 @@ from repro.configs.base import ConsensusSpec, HsadmmConfig
 from repro.core import init_state, consensus_step, EngineSpec
 from repro.core.sparsity import GroupRule, LeafAxis, SparsityPlan
 from repro.dist import hlo
+from repro.launch.mesh import make_host_mesh
 from repro.train.engine import _walk
 
 codec = sys.argv[1]
@@ -386,14 +387,15 @@ spec = EngineSpec(plan=plan,
                   use_momentum=False, stack_map=())
 params0 = {"w": jax.random.normal(jax.random.PRNGKey(0), (32, 8))}
 state = init_state(params0, spec)
-mesh = jax.make_mesh((4,), ("data",))
+mesh = make_host_mesh()
 state = _walk(state, lambda p, x: jax.device_put(
     x, NamedSharding(mesh, P("data") if getattr(x, "ndim", 0) > 0
                      and x.shape[0] == 4 else P())))
 txt = jax.jit(lambda s: consensus_step(s, spec, frozen=True)) \
     .lower(state).compile().as_text()
 colls = hlo.collective_stats(txt, model=1, data=4, node=2)
-print(json.dumps([[c.kind, c.payload_bytes, c.group_size] for c in colls]))
+print(json.dumps([[c.kind, c.payload_bytes, c.group_size, c.tensor_bytes]
+                  for c in colls]))
 """
 
 
@@ -412,11 +414,14 @@ def test_measured_hlo_payloads_match_wire_bytes(codec):
                        capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stderr
     colls = json.loads(r.stdout.strip().splitlines()[-1])
-    payloads = [p for _, p, _ in colls]
+    payloads = [p for _, p, _, _ in colls]
     # compact payload: one rule, keep=16 of 32 groups -> (16, 8) f32
     if codec == "dense":
         expected = get_codec("dense").wire_bytes((16, 8), "float32")
-        assert expected in payloads          # the compact all-reduce
+        # the compact all-reduce; XLA's all-reduce combiner may carry a
+        # scalar reduction in the same instruction, so match the tensor
+        tensors = [b for c in colls if c[0] == "all-reduce" for b in c[3]]
+        assert expected in tensors
     elif codec == "q8":
         # q8 ring: g-1 shifts, each moving the s8 buffer + its f32
         # per-row scales; s8 elems + scale bytes == wire_bytes exactly

@@ -130,7 +130,8 @@ import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.dist import hlo
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(model=2)
 x = jax.device_put(jnp.arange(32.0).reshape(4, 8),
                    NamedSharding(mesh, P("data", "model")))
 f = jax.jit(lambda x: x.reshape(2, 2, 8).sum(0),

@@ -6,8 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref, wire
-from repro.kernels.compact import gather_groups
-from repro.models.ssm import ssd_scan
+from repro.kernels.fused_prox_sgd import fused_prox_sgd_dyn
 
 
 @pytest.mark.parametrize("shape", [(4, 128), (6, 128, 256), (2, 3, 64, 384),
@@ -74,6 +73,26 @@ def test_prox_sgd_update_fallbacks():
         rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("R,C", [(13, 300), (300, 576), (8, 128), (1, 7)])
+def test_fused_prox_sgd_dyn_padded_grid(R, C):
+    """Blocks are (8, 128)-aligned or whole dims (the TPU tiling rule);
+    a dim they do not divide gets a padded final block whose pad never
+    reaches the outputs."""
+    k = jax.random.PRNGKey(4)
+    xs = [jax.random.normal(jax.random.fold_in(k, i), (R, C))
+          for i in range(5)]
+    rho = jax.random.uniform(jax.random.fold_in(k, 9), (R, 1)) + 0.1
+    eta = jnp.full((1, 1), 3e-3, jnp.float32)
+    t, m = fused_prox_sgd_dyn(*xs, rho, eta, momentum=0.9, block_r=8,
+                              block_c=128, interpret=True)
+    mr = 0.9 * xs[4] + xs[1] + rho * (xs[0] - xs[2] + xs[3])
+    tr = xs[0] - 3e-3 * mr
+    np.testing.assert_allclose(np.asarray(m), np.asarray(mr),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(t), np.asarray(tr),
+                               rtol=1e-6, atol=1e-6)
+
+
 @pytest.mark.parametrize("C,B", [(64, 24), (128, 64), (32, 8)])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_compact_expand(C, B, dtype):
@@ -88,7 +107,8 @@ def test_compact_expand(C, B, dtype):
     np.testing.assert_array_equal(np.asarray(e), np.asarray(ref_e))
 
 
-@pytest.mark.parametrize("G,C,K", [(5, 128, 384), (1, 64, 1024), (8, 16, 48)])
+@pytest.mark.parametrize("G,C,K", [(5, 128, 384), (1, 64, 1024), (8, 16, 48),
+                                   (2, 300, 1100)])
 def test_group_norms(G, C, K):
     x = jax.random.normal(jax.random.PRNGKey(2), (G, C, K))
     np.testing.assert_allclose(np.asarray(ops.group_norms_sq(x)),
@@ -96,41 +116,9 @@ def test_group_norms(G, C, K):
                                rtol=1e-5)
 
 
-@pytest.mark.parametrize("T,chunk,H,P,N", [(64, 16, 8, 16, 16),
-                                           (48, 8, 4, 8, 8),
-                                           (32, 32, 8, 16, 16)])
-def test_ssd_chunk_scan(T, chunk, H, P, N):
-    k = jax.random.PRNGKey(3)
-    B = 2
-    x = jax.random.normal(k, (B, T, H, P)) * 0.5
-    dt = jax.nn.softplus(jax.random.normal(jax.random.fold_in(k, 1),
-                                           (B, T, H)))
-    A = -jnp.exp(jax.random.normal(jax.random.fold_in(k, 2), (H,)) * 0.3)
-    Bm = jax.random.normal(jax.random.fold_in(k, 3), (B, T, N))
-    Cm = jax.random.normal(jax.random.fold_in(k, 4), (B, T, N))
-    y, h = ops.ssd_chunk_scan(x, dt, A, Bm, Cm, chunk=chunk, block_h=4)
-    yr, hr = ssd_scan(x, dt, A, Bm, Cm, chunk=chunk)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
-                               rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(h), np.asarray(hr),
-                               rtol=2e-4, atol=2e-4)
-
-
 # ---------------------------------------------------------------------------
 # wire-path kernels (kernels/wire.py) vs ref.py oracles
 # ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("R,block_r", [(7, 4), (13, 8), (257, 256), (5, 256)])
-def test_gather_groups_prime_rows(R, block_r):
-    """Regression for the block-size degradation: a prime/odd R used to
-    shrink the row block down to br=1 (R single-row grid programs); the
-    padded pl.cdiv grid must stay exact on the non-dividing final block."""
-    k = jax.random.PRNGKey(0)
-    x = jax.random.normal(k, (R, 13))
-    idx = jnp.sort(jax.random.permutation(k, 13)[:5]).astype(jnp.int32)
-    out = gather_groups(x, idx, block_r=block_r, interpret=True)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(x[:, idx]))
 
 
 @pytest.mark.parametrize("R,C", [(7, 13), (4, 128), (257, 6), (1, 1)])
@@ -143,80 +131,17 @@ def test_quantize_rows_vs_ref(R, C):
     np.testing.assert_allclose(np.asarray(s), np.asarray(sr), rtol=1e-6)
 
 
-@pytest.mark.parametrize("R,C,B", [(7, 23, 11), (4, 64, 64), (9, 16, 1)])
-def test_gather_quantize_vs_ref(R, C, B):
-    k = jax.random.PRNGKey(2)
-    x = jax.random.normal(k, (R, C))
-    idx = jnp.sort(jax.random.permutation(k, C)[:B]).astype(jnp.int32)
-    q, s = wire.gather_quantize(x, idx, block_r=4, interpret=True)
-    qr, sr = ref.gather_quantize_ref(x, idx)
-    np.testing.assert_array_equal(np.asarray(q), np.asarray(qr))
-    np.testing.assert_allclose(np.asarray(s), np.asarray(sr), rtol=1e-6)
-
-
-@pytest.mark.parametrize("R,C,B", [(7, 23, 11), (3, 8, 8)])
-def test_gather_dequantize_vs_ref(R, C, B):
-    """Fused decode: dequantize + inverse-permutation zero-fill gather
-    equals the two-pass reference."""
-    k = jax.random.PRNGKey(3)
-    x = jax.random.normal(k, (R, C))
-    idx = jnp.sort(jax.random.permutation(k, C)[:B]).astype(jnp.int32)
-    q, s = ref.gather_quantize_ref(x, idx)
-    inv = jnp.full((C,), B, jnp.int32).at[idx].set(
-        jnp.arange(B, dtype=jnp.int32))
-    qp = jnp.pad(q, ((0, 0), (0, 1)))
-    out = wire.gather_dequantize(qp, s, inv, block_r=4, interpret=True)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(ref.gather_dequantize_ref(qp, s,
-                                                                    inv)),
-                               rtol=1e-6)
-    # dropped channels are exactly zero; kept ones match within quant err
-    mask = np.zeros(C); mask[np.asarray(idx)] = 1
-    assert np.all(np.asarray(out)[:, mask == 0] == 0.0)
-
-
-@pytest.mark.parametrize("R,C", [(7, 13), (4, 16), (5, 1), (257, 7)])
+@pytest.mark.parametrize("R,C", [(7, 13), (4, 16), (5, 1), (257, 7),
+                                 (9, 600), (3, 512), (2, 257)])
 def test_quantize_pack_q4_vs_ref(R, C):
-    """Odd minor dims exercise the zero pad nibble."""
+    """Odd minor dims exercise the zero pad nibble; C > 256 runs the
+    looped 256-lane MXU pack steps, with and without a tail step."""
     x = jax.random.normal(jax.random.PRNGKey(4), (R, C))
     p, s = wire.quantize_pack_q4(x, block_r=8, interpret=True)
     prr, srr = ref.quantize_pack_q4_ref(x)
     assert p.dtype == jnp.uint8 and p.shape == (R, (C + 1) // 2)
     np.testing.assert_array_equal(np.asarray(p), np.asarray(prr))
     np.testing.assert_allclose(np.asarray(s), np.asarray(srr), rtol=1e-6)
-
-
-@pytest.mark.parametrize("R,C,B", [(7, 23, 11), (4, 16, 3)])
-def test_gather_quantize_q4_vs_ref(R, C, B):
-    k = jax.random.PRNGKey(5)
-    x = jax.random.normal(k, (R, C))
-    idx = jnp.sort(jax.random.permutation(k, C)[:B]).astype(jnp.int32)
-    p, s = wire.gather_quantize_q4(x, idx, block_r=4, interpret=True)
-    prr, srr = ref.quantize_pack_q4_ref(x[:, idx])
-    np.testing.assert_array_equal(np.asarray(p), np.asarray(prr))
-    np.testing.assert_allclose(np.asarray(s), np.asarray(srr), rtol=1e-6)
-
-
-@pytest.mark.parametrize("R,C,B", [(7, 23, 11), (3, 8, 5)])
-def test_unpack_gather_dequantize_q4_vs_ref(R, C, B):
-    """Fused q4 decode (unpack + dequantize + zero-fill) == unpack_q4_ref
-    composed with the dequantize reference."""
-    k = jax.random.PRNGKey(6)
-    x = jax.random.normal(k, (R, C))
-    idx = jnp.sort(jax.random.permutation(k, C)[:B]).astype(jnp.int32)
-    p, s = ref.quantize_pack_q4_ref(x[:, idx])
-    Cp = p.shape[1]
-    # dropped channels read nibble 2*Cp of the zero-padded packed buffer
-    inv = jnp.full((C,), 2 * Cp, jnp.int32).at[idx].set(
-        jnp.arange(B, dtype=jnp.int32))
-    pp = jnp.pad(p, ((0, 0), (0, 1)))
-    out = wire.unpack_gather_dequantize_q4(pp, s, inv, block_r=4,
-                                           interpret=True)
-    q_un = ref.unpack_q4_ref(pp, 2 * (Cp + 1))
-    want = np.asarray(q_un)[:, np.asarray(inv)] * np.asarray(s)
-    np.testing.assert_allclose(np.asarray(out), want, rtol=1e-6)
-    mask = np.zeros(C); mask[np.asarray(idx)] = 1
-    assert np.all(np.asarray(out)[:, mask == 0] == 0.0)
 
 
 @pytest.mark.parametrize("shape", [(2, 3, 17), (9,), ()])

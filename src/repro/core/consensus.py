@@ -51,6 +51,7 @@ def _norm_sq_per_stack(x: jnp.ndarray, stack_ndims: int,
     return jnp.sum(jnp.square(x.astype(jnp.float32)), axis=axes)
 
 
+@jax.named_scope("consensus/masks")
 def _make_masks(state, spec, mask_src, frozen):
     """Phase-3 mask generation + global synchronization."""
     new_masks, idxs, info = {}, {}, {}
@@ -187,8 +188,6 @@ def consensus_step(state: dict, spec: EngineSpec, frozen: bool = False,
             wire_new[k - 1] = unflatten(new_cst_flat)
         return unflatten(out_flat)
 
-    payload0 = jax.tree.map(lambda t, uu: t + uu, theta, u)
-
     def cand1(buf_tree, z2v_tree):
         """z~_1 = (rho1*sum_j w_j(theta+u) + rho2*(z2 - v1)) / gamma (Eq. 9)."""
         out = {}
@@ -207,126 +206,138 @@ def consensus_step(state: dict, spec: EngineSpec, frozen: bool = False,
             out[key] = (num / den).astype(b.dtype)
         return unflatten(out)
 
-    z2v = None
-    if K > 1:
-        z2v = jax.tree.map(lambda z2, v1: ungroup(z2, levels[1]) - v1,
-                           zs_old[1], vs_old[0])
-
+    # ---- Phases 2-3: the level-1 exchange and the masks --------------------
     info: dict = {}
-    if spec.boundary_compact(1, codecs):
-        # masks from per-worker payloads; level-1 reduce is already compact.
-        new_masks, idxs, minfo = _make_masks(state, spec, payload0, frozen)
-        info.update(minfo)
-        pc = compact_params(payload0, plan, idxs, offset=1)
-        buf = wire_reduce(pc, 1, levels[0], 0)   # compact collective
-        z2v_c = compact_params(z2v, plan, idxs, offset=1) if K > 1 else None
-        z1c = cand1(buf, z2v_c)
-        z1 = expand_params(z1c, plan, idxs, fulls, offset=1)  # recovery
-    else:
-        buf = wire_reduce(payload0, 1, levels[0], 0)  # dense intra AllReduce
-        z1t = cand1(buf, z2v)
-        new_masks, idxs, minfo = _make_masks(state, spec, z1t, frozen)
-        info.update(minfo)
-        z1 = z1t
-        for rule in plan.rules:                  # projection Pi_S (Eq. 10)
-            z1 = apply_mask_rule(z1, rule, new_masks[rule.name]["mask"][None],
-                                 offset=1)
+    with jax.named_scope("consensus/level1"):
+        payload0 = jax.tree.map(lambda t, uu: t + uu, theta, u)
+        z2v = None
+        if K > 1:
+            z2v = jax.tree.map(lambda z2, v1: ungroup(z2, levels[1]) - v1,
+                               zs_old[1], vs_old[0])
+
+        if spec.boundary_compact(1, codecs):
+            # masks from per-worker payloads; level-1 reduce is already
+            # compact
+            new_masks, idxs, minfo = _make_masks(state, spec, payload0,
+                                                 frozen)
+            info.update(minfo)
+            pc = compact_params(payload0, plan, idxs, offset=1)
+            buf = wire_reduce(pc, 1, levels[0], 0)   # compact collective
+            z2v_c = compact_params(z2v, plan, idxs, offset=1) \
+                if K > 1 else None
+            z1c = cand1(buf, z2v_c)
+            z1 = expand_params(z1c, plan, idxs, fulls, offset=1)  # recovery
+        else:
+            # dense intra AllReduce
+            buf = wire_reduce(payload0, 1, levels[0], 0)
+            z1t = cand1(buf, z2v)
+            new_masks, idxs, minfo = _make_masks(state, spec, z1t, frozen)
+            info.update(minfo)
+            z1 = z1t
+            for rule in plan.rules:              # projection Pi_S (Eq. 10)
+                z1 = apply_mask_rule(z1, rule,
+                                     new_masks[rule.name]["mask"][None],
+                                     offset=1)
 
     # ---- Phase 4: levels 2..K ----------------------------------------------
     zs_new = [z1]
     for k in range(2, K + 1):
-        g = levels[k - 1]
-        payload = jax.tree.map(lambda zk, vk: zk + vk, zs_new[-1],
-                               vs_old[k - 2])
-        zkv = None
-        if k < K:
-            zkv = jax.tree.map(lambda zn, vn: ungroup(zn, levels[k]) - vn,
-                               zs_old[k], vs_old[k - 1])
-        do_compact = spec.boundary_compact(k, codecs)
-        if do_compact:
-            payload = compact_params(payload, plan, idxs, offset=1)
-            if zkv is not None:
-                zkv = compact_params(zkv, plan, idxs, offset=1)
-        red = wire_reduce(payload, k, g, k - 1)  # level-k collective
+        with jax.named_scope(f"consensus/level{k}"):
+            g = levels[k - 1]
+            payload = jax.tree.map(lambda zk, vk: zk + vk, zs_new[-1],
+                                   vs_old[k - 2])
+            zkv = None
+            if k < K:
+                zkv = jax.tree.map(lambda zn, vn: ungroup(zn, levels[k]) - vn,
+                                   zs_old[k], vs_old[k - 1])
+            do_compact = spec.boundary_compact(k, codecs)
+            if do_compact:
+                payload = compact_params(payload, plan, idxs, offset=1)
+                if zkv is not None:
+                    zkv = compact_params(zkv, plan, idxs, offset=1)
+            red = wire_reduce(payload, k, g, k - 1)  # level-k collective
 
-        out = {}
-        for key in leaf_keys(red):
-            b = get_leaf(red, key)
-            sn = spec.stack_ndims(key)
-            wsum = wk_for(key)[k].reshape(
-                (-1,) + (1,) * (b.ndim - 1)).astype(b.dtype)
-            if k == K:                           # Eq. 11: weighted mean
-                out[key] = (b / jnp.maximum(wsum, 1e-12)).astype(b.dtype)
-            else:
-                rk = bcast_rho(get_leaf(rho[k - 1], key), b, sn, 1)
-                rk1 = bcast_rho(get_leaf(rho[k], key), b, sn, 1)
-                out[key] = ((rk * b + rk1 * get_leaf(zkv, key))
-                            / (rk * wsum + rk1)).astype(b.dtype)
-        zk = unflatten(out)
-        if do_compact:
-            zk = expand_params(zk, plan, idxs, fulls, offset=1)  # zero-fill
-        zs_new.append(zk)
-
-    # ---- Phase 5: duals (Eq. 12-13) -----------------------------------------
-    z1b = jax.tree.map(lambda z: ungroup(z, levels[0]), zs_new[0])
-    u_new = jax.tree.map(lambda uu, th, zz: uu + (th - zz.astype(th.dtype)),
-                         u, theta, z1b)
-    vs_new = []
-    for k in range(1, K):
-        zkp = jax.tree.map(lambda z: ungroup(z, levels[k]), zs_new[k])
-        vs_new.append(jax.tree.map(lambda vv, zk, zp: vv + (zk - zp),
-                                   vs_old[k - 1], zs_new[k - 1], zkp))
-
-    # ---- residuals + layer-wise adaptive penalties (paper §3.4) -------------
-    rho_new = []
-    u_scaled, vs_scaled = u_new, list(vs_new)
-    r_tot = jnp.zeros((), jnp.float32)
-    s_tot = jnp.zeros((), jnp.float32)
-    for b in range(K):  # boundary b: level-b <-> level-(b+1)
-        if b == 0:
-            lhs, rhs_new, rhs_old = theta, zs_new[0], zs_old[0]
-        else:
-            lhs, rhs_new, rhs_old = zs_new[b - 1], zs_new[b], zs_old[b]
-        gb = levels[b]
-        rho_b_new, factors = {}, {}
-        for key in leaf_keys(rho[b]):
-            sn = spec.stack_ndims(key)
-            x = get_leaf(lhs, key)
-            zn = ungroup(get_leaf(rhs_new, key), gb)
-            r2 = _norm_sq_per_stack(x - zn.astype(x.dtype), sn, 1)
-            dz = get_leaf(rhs_new, key) - get_leaf(rhs_old, key)
-            s2 = _norm_sq_per_stack(dz, sn, 1)
-            rho_b = get_leaf(rho[b], key)
-            r_n = jnp.sqrt(r2)
-            s_n = rho_b * jnp.sqrt(s2)
-            f = jnp.where(r_n > hp.adapt_mu * s_n, hp.adapt_tau,
-                          jnp.where(s_n > hp.adapt_mu * r_n,
-                                    1.0 / hp.adapt_tau, 1.0))
-            new_rho = jnp.clip(rho_b * f, 1e-8, hp.rho_max)
-            rho_b_new[key] = new_rho
-            factors[key] = rho_b / new_rho  # scaled-dual rescale (Boyd §3.4.1)
-            r_tot = r_tot + jnp.sum(r2)
-            s_tot = s_tot + jnp.sum(s2)
-            if detail:
-                tag = "r_intra" if b == 0 else f"r_inter{b}"
-                info.setdefault(tag, {})[key] = r_n
-        rho_new.append(unflatten(rho_b_new))
-
-        def _rescale(tree):
             out = {}
-            for key in leaf_keys(tree):
-                x = get_leaf(tree, key)
-                f = bcast_rho(factors[key].astype(jnp.float32), x,
-                              spec.stack_ndims(key), 1).astype(x.dtype)
-                out[key] = x * f
-            return unflatten(out)
-        if b == 0:
-            u_scaled = _rescale(u_new)
-        else:
-            vs_scaled[b - 1] = _rescale(vs_new[b - 1])
+            for key in leaf_keys(red):
+                b = get_leaf(red, key)
+                sn = spec.stack_ndims(key)
+                wsum = wk_for(key)[k].reshape(
+                    (-1,) + (1,) * (b.ndim - 1)).astype(b.dtype)
+                if k == K:                           # Eq. 11: weighted mean
+                    out[key] = (b / jnp.maximum(wsum, 1e-12)).astype(b.dtype)
+                else:
+                    rk = bcast_rho(get_leaf(rho[k - 1], key), b, sn, 1)
+                    rk1 = bcast_rho(get_leaf(rho[k], key), b, sn, 1)
+                    out[key] = ((rk * b + rk1 * get_leaf(zkv, key))
+                                / (rk * wsum + rk1)).astype(b.dtype)
+            zk = unflatten(out)
+            if do_compact:
+                # zero-fill recovery
+                zk = expand_params(zk, plan, idxs, fulls, offset=1)
+            zs_new.append(zk)
 
-    info["r_primal"] = jnp.sqrt(r_tot)
-    info["s_dual"] = jnp.sqrt(s_tot)
+    with jax.named_scope("consensus/update"):
+        # ---- Phase 5: duals (Eq. 12-13) -------------------------------------
+        z1b = jax.tree.map(lambda z: ungroup(z, levels[0]), zs_new[0])
+        u_new = jax.tree.map(
+            lambda uu, th, zz: uu + (th - zz.astype(th.dtype)), u, theta, z1b)
+        vs_new = []
+        for k in range(1, K):
+            zkp = jax.tree.map(lambda z: ungroup(z, levels[k]), zs_new[k])
+            vs_new.append(jax.tree.map(lambda vv, zk, zp: vv + (zk - zp),
+                                       vs_old[k - 1], zs_new[k - 1], zkp))
+
+        # ---- residuals + layer-wise adaptive penalties (paper §3.4) ---------
+        rho_new = []
+        u_scaled, vs_scaled = u_new, list(vs_new)
+        r_tot = jnp.zeros((), jnp.float32)
+        s_tot = jnp.zeros((), jnp.float32)
+        for b in range(K):  # boundary b: level-b <-> level-(b+1)
+            if b == 0:
+                lhs, rhs_new, rhs_old = theta, zs_new[0], zs_old[0]
+            else:
+                lhs, rhs_new, rhs_old = zs_new[b - 1], zs_new[b], zs_old[b]
+            gb = levels[b]
+            rho_b_new, factors = {}, {}
+            for key in leaf_keys(rho[b]):
+                sn = spec.stack_ndims(key)
+                x = get_leaf(lhs, key)
+                zn = ungroup(get_leaf(rhs_new, key), gb)
+                r2 = _norm_sq_per_stack(x - zn.astype(x.dtype), sn, 1)
+                dz = get_leaf(rhs_new, key) - get_leaf(rhs_old, key)
+                s2 = _norm_sq_per_stack(dz, sn, 1)
+                rho_b = get_leaf(rho[b], key)
+                r_n = jnp.sqrt(r2)
+                s_n = rho_b * jnp.sqrt(s2)
+                f = jnp.where(r_n > hp.adapt_mu * s_n, hp.adapt_tau,
+                              jnp.where(s_n > hp.adapt_mu * r_n,
+                                        1.0 / hp.adapt_tau, 1.0))
+                new_rho = jnp.clip(rho_b * f, 1e-8, hp.rho_max)
+                rho_b_new[key] = new_rho
+                # scaled-dual rescale (Boyd §3.4.1)
+                factors[key] = rho_b / new_rho
+                r_tot = r_tot + jnp.sum(r2)
+                s_tot = s_tot + jnp.sum(s2)
+                if detail:
+                    tag = "r_intra" if b == 0 else f"r_inter{b}"
+                    info.setdefault(tag, {})[key] = r_n
+            rho_new.append(unflatten(rho_b_new))
+
+            def _rescale(tree):
+                out = {}
+                for key in leaf_keys(tree):
+                    x = get_leaf(tree, key)
+                    f = bcast_rho(factors[key].astype(jnp.float32), x,
+                                  spec.stack_ndims(key), 1).astype(x.dtype)
+                    out[key] = x * f
+                return unflatten(out)
+            if b == 0:
+                u_scaled = _rescale(u_new)
+            else:
+                vs_scaled[b - 1] = _rescale(vs_new[b - 1])
+
+        info["r_primal"] = jnp.sqrt(r_tot)
+        info["s_dual"] = jnp.sqrt(s_tot)
 
     new_state = dict(state)
     new_state.update(theta=theta, u=u_scaled, z=zs_new, v=vs_scaled,

@@ -299,15 +299,12 @@ def local_step(state: dict, batch, loss_fn: Callable, spec: EngineSpec,
     through the penalty).  grad_accum > 1 splits the per-worker batch into
     microbatches and accumulates grads in a scan (activation memory drops
     grad_accum-fold).  Returns (new_state, mean loss).
-    """
-    levels = spec.consensus.levels
-    theta = state["theta"]
-    if spec.solo:
-        u = z1_w = None
-    else:
-        u = state["u"]
-        z1_w = jax.tree.map(lambda z: ungroup(z, levels[0]), state["z"][0])
 
+    The forward and backward pass run under the ``local/model`` named
+    scope and the per-leaf update under ``local/update``: every HLO
+    instruction they lower to carries the scope in its ``op_name``.
+    """
+    theta = state["theta"]
     if grad_accum > 1:
         def worker_vg(th, bw):
             mb = jax.tree.map(
@@ -327,8 +324,22 @@ def local_step(state: dict, batch, loss_fn: Callable, spec: EngineSpec,
         grad_fn = jax.vmap(worker_vg)
     else:
         grad_fn = jax.vmap(jax.value_and_grad(loss_fn))
-    losses, g = grad_fn(theta, batch)
+    with jax.named_scope("local/model"):
+        losses, g = grad_fn(theta, batch)
+        loss = jnp.mean(losses)
+    with jax.named_scope("local/update"):
+        return _local_update(state, g, spec, eta), loss
 
+
+def _local_update(state: dict, g: Params, spec: EngineSpec, eta) -> dict:
+    """theta and momentum after one prox-SGD step on the gradients ``g``."""
+    theta = state["theta"]
+    if spec.solo:
+        u = z1_w = None
+    else:
+        u = state["u"]
+        z1_w = jax.tree.map(lambda z: ungroup(z, spec.consensus.levels[0]),
+                            state["z"][0])
     rho1 = state.get("rho", [None])[0]
 
     def upd(key, th):
@@ -353,12 +364,11 @@ def local_step(state: dict, batch, loss_fn: Callable, spec: EngineSpec,
         t, m = upd(key, get_leaf(theta, key))
         new_theta[key] = t
         new_mom[key] = m
-    theta = _unflatten(new_theta)
     out = dict(state)
-    out["theta"] = theta
+    out["theta"] = _unflatten(new_theta)
     if spec.use_momentum:
         out["mom"] = _unflatten(new_mom)
-    return out, jnp.mean(losses)
+    return out
 
 
 def _prox_update(spec: EngineSpec, theta, g, z, u, mom, rho, eta):

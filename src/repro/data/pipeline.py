@@ -1,6 +1,16 @@
 """Host input pipeline: deterministic stream -> device, with background
 prefetch so host batch synthesis overlaps device compute (the paper's Phase
-1 is compute-bound; input stall would pollute its timing benchmarks)."""
+1 is compute-bound; input stall would pollute its timing benchmarks).
+
+Each stage is a ``dist.monitor.span``, so a profiler trace and the span
+counters show where the feed spends its time:
+
+  ``repro.feed.load``      superbatches: the E ``next()`` calls upstream
+  ``repro.feed.stack``     superbatches: stacking the E batches
+  ``repro.feed.produce``   prefetch worker: ``next()`` on its upstream
+  ``repro.feed.put_wait``  prefetch worker: blocked on a full queue
+  ``repro.feed.get_wait``  prefetch consumer: blocked on an empty queue
+"""
 from __future__ import annotations
 
 import queue
@@ -8,6 +18,8 @@ import threading
 from typing import Iterator
 
 import jax
+
+from ..dist.monitor import span
 
 
 def batches(stream, extra_inputs=(), shape=None, start_step: int = 0
@@ -35,8 +47,11 @@ def superbatches(it: Iterator, e: int) -> Iterator:
     bundle per outer round; wrap with :func:`prefetch` so bundle
     assembly overlaps the previous round's device compute)."""
     while True:
-        bs = [next(it) for _ in range(e)]
-        yield jax.tree.map(lambda *xs: jax.numpy.stack(xs), *bs)
+        with span("repro.feed.load"):
+            bs = [next(it) for _ in range(e)]
+        with span("repro.feed.stack"):
+            sb = jax.tree.map(lambda *xs: jax.numpy.stack(xs), *bs)
+        yield sb
 
 
 def superbatch_chunks(it: Iterator, e: int, steps: int) -> Iterator:
@@ -59,15 +74,21 @@ def prefetch(it: Iterator, size: int = 2) -> Iterator:
 
     def worker():
         try:
-            for item in it:
-                q.put(item)
+            while True:
+                with span("repro.feed.produce"):
+                    item = next(it, stop)
+                if item is stop:
+                    break
+                with span("repro.feed.put_wait"):
+                    q.put(item)
         finally:
             q.put(stop)
 
     t = threading.Thread(target=worker, daemon=True)
     t.start()
     while True:
-        item = q.get()
+        with span("repro.feed.get_wait"):
+            item = q.get()
         if item is stop:
             return
         yield item

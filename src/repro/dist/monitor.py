@@ -1,4 +1,4 @@
-"""Compilation/dispatch accounting hooks.
+"""Compilation/dispatch accounting hooks and the program's host spans.
 
 The fused-round contract ("one outer round == one jitted dispatch, two
 executables per run") is a perf invariant that silently regresses: an
@@ -12,7 +12,13 @@ without failing any correctness test.  This module gives the test suite
     built, including AOT ``.compile()`` calls), their seconds, and the
     executables loaded from the persistent compilation cache instead;
   * :func:`counting` — wraps any callable (e.g. an engine's jitted round
-    fn) with an invocation counter, for asserting dispatches-per-round.
+    fn) with an invocation counter, for asserting dispatches-per-round;
+  * :func:`span` — names a phase of the host's work (``repro.feed.*`` in
+    the input pipeline, ``repro.round.*`` in ``train()``): under the JAX
+    profiler it is a ``TraceAnnotation`` on the host plane of the trace,
+    on the clock of the device events, and with or without a profiler
+    it adds its count and seconds to a process-wide table that
+    :func:`span_totals` reads.
 
 jax.monitoring has no listener *removal* API, so one module-level
 listener is installed lazily and kept; nesting/overlap of
@@ -20,7 +26,10 @@ listener is installed lazily and kept; nesting/overlap of
 """
 from __future__ import annotations
 
+import collections
 import contextlib
+import threading
+import time
 from dataclasses import dataclass, field
 
 import jax
@@ -82,7 +91,6 @@ def probe_seconds(fn, *args, reps: int = 3, warmup: int = 1
     TIMED calls (a short measured probe — repro.comm.select uses this
     for codec selection; nonzero steady-state compiles mean the probe
     timed XLA, not the computation, and should be discarded)."""
-    import time
     out = None
     for _ in range(max(warmup, 1)):
         out = fn(*args)
@@ -118,3 +126,60 @@ def counting(fn, label: str = "") -> tuple:
     """(wrapped_fn, CallCounter) for a single callable."""
     c = CallCounter()
     return c.wrap(fn, label), c
+
+
+# ---------------------------------------------------------------------------
+# host spans
+# ---------------------------------------------------------------------------
+
+SPAN_LOG = 1 << 16        # the most recent spans kept with their times
+_span_lock = threading.Lock()
+_span_totals: dict = {}   # name -> [count, seconds]
+_span_log: collections.deque = collections.deque(maxlen=SPAN_LOG)
+
+
+@dataclass(frozen=True)
+class SpanTotal:
+    count: int = 0
+    seconds: float = 0.0
+
+
+@contextlib.contextmanager
+def span(name: str):
+    """``with span("repro.feed.load"): ...`` — a profiler annotation of
+    the block, and one more count and the block's seconds for ``name``
+    (safe from any thread; spans nest)."""
+    t0 = time.perf_counter()
+    try:
+        with jax.profiler.TraceAnnotation(name):
+            yield
+    finally:
+        t1 = time.perf_counter()
+        with _span_lock:
+            tot = _span_totals.setdefault(name, [0, 0.0])
+            tot[0] += 1
+            tot[1] += t1 - t0
+            _span_log.append((name, t0, t1))
+
+
+def span_totals(since: float = None, until: float = None) -> dict:
+    """``{name: SpanTotal}``.  Without arguments, every span since the
+    process started: a snapshot that callers subtract, as
+    :func:`compile_count` does.  With ``since``/``until`` (readings of
+    ``time.perf_counter()``), the part of the last ``SPAN_LOG`` spans
+    inside that interval: the seconds each overlaps it, and a count for
+    each that ends in it."""
+    with _span_lock:
+        if since is None and until is None:
+            return {n: SpanTotal(c, s) for n, (c, s) in _span_totals.items()}
+        log = list(_span_log)
+    lo = float("-inf") if since is None else since
+    hi = float("inf") if until is None else until
+    out: dict = {}
+    for name, t0, t1 in log:
+        overlap = min(t1, hi) - max(t0, lo)
+        if overlap < 0 or (overlap == 0 and t1 != t0):
+            continue
+        c, s = out.get(name, (0, 0.0))
+        out[name] = (c + (lo <= t1 < hi), s + overlap)
+    return {n: SpanTotal(c, s) for n, (c, s) in out.items()}

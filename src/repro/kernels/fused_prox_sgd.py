@@ -57,6 +57,7 @@ def fused_prox_sgd(theta, g, z, u, mom, *, eta, rho, momentum,
         in_specs=[bs] * 5,
         out_specs=(bs, bs),
         interpret=interpret,
+        name="prox_sgd",
     )(theta, g, z, u, mom)
 
 
@@ -90,4 +91,5 @@ def fused_prox_sgd_dyn(theta, g, z, u, mom, rho_col, eta, *, momentum,
         in_specs=[bs] * 5 + [rs, es],
         out_specs=(bs, bs),
         interpret=interpret,
+        name="prox_sgd",
     )(theta, g, z, u, mom, rho_col, eta)

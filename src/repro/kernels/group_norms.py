@@ -45,6 +45,7 @@ def group_norms_sq(x, *, block_c=128, block_k=512, interpret=False):
         in_specs=[pl.BlockSpec((None, bc, bk), lambda g, c, k: (g, c, k))],
         out_specs=pl.BlockSpec((None, 1, bc), lambda g, c, k: (g, 0, c)),
         interpret=interpret,
+        name="group_norms_sq",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(x)

@@ -77,6 +77,7 @@ def quantize_rows(x, *, levels=127, block_r=256, interpret=False):
         out_specs=(pl.BlockSpec((br, C), lambda i: (i, 0)),
                    pl.BlockSpec((br, 1), lambda i: (i, 0))),
         interpret=interpret,
+        name="quantize_rows",
     )(x)
 
 
@@ -141,4 +142,5 @@ def quantize_pack_q4(x, *, block_r=256, interpret=False):
         out_specs=(pl.BlockSpec((br, Cp), lambda i: (i, 0)),
                    pl.BlockSpec((br, 1), lambda i: (i, 0))),
         interpret=interpret,
+        name="quantize_pack_q4",
     )(x)

@@ -64,6 +64,7 @@ from ..data.pipeline import batches, prefetch, superbatches
 from ..data.synthetic import make_stream
 from ..dist import checkpoint as ckpt
 from ..dist import hlo
+from ..dist.monitor import span
 from .engine import Engine
 
 
@@ -474,7 +475,8 @@ def _train(engine: Engine, run: RunConfig) -> tuple[dict, TrainReport]:
         nonlocal frozen, stop, t_block, host_overhead
         if not pending:
             return
-        vals = jax.device_get([m for (_, _, m) in pending])
+        with span("repro.round.drain"):
+            vals = jax.device_get([m for (_, _, m) in pending])
         per_round = max(time.time() - t_block - host_overhead, 0.0) \
             / len(pending)
         report.wall_times.extend([per_round] * len(pending))
@@ -515,47 +517,48 @@ def _train(engine: Engine, run: RunConfig) -> tuple[dict, TrainReport]:
             drain()
             if stop:
                 break   # converged in the drained block: skip the retrace
-            t_r = time.time()
-            if staleness:
-                # drain the in-flight consensus before migrating: the
-                # overlapped state still carries one un-reduced theta,
-                # and the shrunk plan must migrate a buffer the frozen
-                # masks actually describe — not a pending one
-                state, _ = engine.flush_pipeline_fn(frozen=True)(state)
-            rc_engine, state = engine.reconfigure(state)
-            if run.wire_auto and not rc_engine.spec.solo:
-                # the start-of-run selection saw full-shape payloads;
-                # re-select on the shrunk byte model (satellite: a map
-                # chosen for full shapes is stale after the retrace)
-                from ..comm.select import AdaptiveWireSelector
-                sel2 = AdaptiveWireSelector().select(rc_engine)
-                rc_engine = sel2.apply(rc_engine)
-                if not any(c.stateful for c in rc_engine.spec.codecs) \
-                        and "wire" in state:
-                    # the reselected candidates are all stateless: the
-                    # old codec's error-feedback buffers are meaningless
-                    # under the new map — drop them so the state matches
-                    # the reselected engine's structure
-                    state = {k2: v for k2, v in state.items()
-                             if k2 != "wire"}
+            with span("repro.round.reconfigure"):
+                t_r = time.time()
+                if staleness:
+                    # drain the in-flight consensus before migrating: the
+                    # overlapped state still carries one un-reduced theta,
+                    # and the shrunk plan must migrate a buffer the frozen
+                    # masks actually describe — not a pending one
+                    state, _ = engine.flush_pipeline_fn(frozen=True)(state)
+                rc_engine, state = engine.reconfigure(state)
+                if run.wire_auto and not rc_engine.spec.solo:
+                    # the start-of-run selection saw full-shape payloads;
+                    # re-select on the shrunk byte model (satellite: a map
+                    # chosen for full shapes is stale after the retrace)
+                    from ..comm.select import AdaptiveWireSelector
+                    sel2 = AdaptiveWireSelector().select(rc_engine)
+                    rc_engine = sel2.apply(rc_engine)
+                    if not any(c.stateful for c in rc_engine.spec.codecs) \
+                            and "wire" in state:
+                        # the reselected candidates are all stateless: the
+                        # old codec's error-feedback buffers are meaningless
+                        # under the new map — drop them so the state matches
+                        # the reselected engine's structure
+                        state = {k2: v for k2, v in state.items()
+                                 if k2 != "wire"}
+                    if log:
+                        log("[loop] wire-auto reselected on shrunk shapes: "
+                            + sel2.to_json())
+                if not rc_engine.spec.solo:
+                    report.wire_map_reconfigured = \
+                        [c.name for c in rc_engine.spec.codecs]
+                round_frz = rc_engine.round_step_fn(frozen=True)
+                _, _, frz_b = round_comm_bytes(rc_engine)
+                report.reconfigured_at = k
+                if report.hlo_comm is not None:
+                    report.hlo_comm["reconfigured"] = _hlo_entry(
+                        rc_engine.round_collectives(frozen=True,
+                                                    shape=run.shape))
                 if log:
-                    log("[loop] wire-auto reselected on shrunk shapes: "
-                        + sel2.to_json())
-            if not rc_engine.spec.solo:
-                report.wire_map_reconfigured = \
-                    [c.name for c in rc_engine.spec.codecs]
-            round_frz = rc_engine.round_step_fn(frozen=True)
-            _, _, frz_b = round_comm_bytes(rc_engine)
-            report.reconfigured_at = k
-            if report.hlo_comm is not None:
-                report.hlo_comm["reconfigured"] = _hlo_entry(
-                    rc_engine.round_collectives(frozen=True,
-                                                shape=run.shape))
-            if log:
-                log(f"[loop] physically reconfigured at outer iter {k}: "
-                    f"frozen-round payload {frz_b/1e6:.2f}MB/round")
-            host_overhead += time.time() - t_r   # migration is host-timed;
-            # the one retrace compile lands in the next round's wall time
+                    log(f"[loop] physically reconfigured at outer iter {k}: "
+                        f"frozen-round payload {frz_b/1e6:.2f}MB/round")
+                host_overhead += time.time() - t_r   # migration is host-timed;
+                # the one retrace compile lands in the next round's wall time
         if run.ft_policy is not None:
             w = run.ft_policy(k, engine.workers)
             state = dict(state, weights=jnp.asarray(w, jnp.float32))
@@ -567,13 +570,18 @@ def _train(engine: Engine, run: RunConfig) -> tuple[dict, TrainReport]:
                 state["class_weights"] = cw
         was_frozen = frozen
         if run.fused_rounds:
-            state, m = (round_frz if frozen else round_dyn)(
-                state, next(it), eta)
+            sb = next(it)
+            with span("repro.round.dispatch"):
+                state, m = (round_frz if frozen else round_dyn)(
+                    state, sb, eta)
         else:
             loss = None
             for _ in range(E):                      # Phase 1 (legacy path)
-                state, loss = local_fn(state, next(it), eta)
-            state, info = (cons_frz if frozen else cons_dyn)(state)
+                batch = next(it)
+                with span("repro.round.dispatch"):
+                    state, loss = local_fn(state, batch, eta)
+            with span("repro.round.dispatch"):
+                state, info = (cons_frz if frozen else cons_dyn)(state)
             m = round_metrics(state, info, loss, engine.spec)
         pending.append((k, was_frozen, m))
         report.executables.append(
@@ -598,16 +606,17 @@ def _train(engine: Engine, run: RunConfig) -> tuple[dict, TrainReport]:
         if run.ckpt_dir and run.ckpt_every > 0 \
                 and (k + 1) % run.ckpt_every == 0:
             drain()   # attribute pending compute before the host transfer
-            t_c = time.time()
-            ckpt.save(run.ckpt_dir, jax.device_get(state),
-                      {"step": k + 1, "arch": cfg.name,
-                       "workers": engine.workers,
-                       "levels": list(engine.consensus.levels),
-                       "reconfigured": rc_engine is not None},
-                      keep=run.ckpt_keep, background=True,
-                      aux=_masks_aux(rc_engine.frozen_masks,
-                                     engine.bundle.plan)
-                      if rc_engine is not None else None)
+            with span("repro.round.checkpoint"):
+                t_c = time.time()
+                ckpt.save(run.ckpt_dir, jax.device_get(state),
+                          {"step": k + 1, "arch": cfg.name,
+                           "workers": engine.workers,
+                           "levels": list(engine.consensus.levels),
+                           "reconfigured": rc_engine is not None},
+                          keep=run.ckpt_keep, background=True,
+                          aux=_masks_aux(rc_engine.frozen_masks,
+                                         engine.bundle.plan)
+                          if rc_engine is not None else None)
             host_overhead += time.time() - t_c
         if stop:
             break

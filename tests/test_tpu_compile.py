@@ -132,3 +132,57 @@ def test_prox_update_runs_per_worker_shard(topo, monkeypatch):
     assert calls and all("f32[576,64]" in l for l in calls)
     assert not any("f32[2304,64]" in l for l in calls)
     assert " all-gather(" not in txt
+
+
+@pytest.mark.parametrize("name,fn,shapes", [
+    ("prox_sgd", lambda *a: fused_prox_sgd_dyn(*a, momentum=0.9),
+     [((256, 128), jnp.float32)] * 5 + [((256, 1), jnp.float32),
+                                        ((1, 1), jnp.float32)]),
+    ("quantize_rows", quantize_rows, [((256, 128), jnp.float32)]),
+    ("quantize_pack_q4", quantize_pack_q4, [((256, 128), jnp.float32)]),
+    ("group_norms_sq", group_norms_sq, [((1, 64, 27), jnp.float32)]),
+])
+def test_kernels_are_named_in_the_chip_executable(one_chip, name, fn,
+                                                  shapes):
+    """A profiler trace names a TPU event by its HLO instruction: each
+    kernel's custom call carries the kernel's name."""
+    txt = _hlo(fn, *shapes, sharding=one_chip)
+    calls = [l for l in txt.splitlines() if "tpu_custom_call" in l]
+    assert calls and all(l.lstrip().startswith(f"%{name}") or
+                         l.lstrip().startswith(f"ROOT %{name}")
+                         for l in calls)
+
+
+def test_round_scopes_reach_the_chip_executable(topo, monkeypatch):
+    """The named scopes of the round survive the chip's compiler: in a
+    smoke CNN round compiled for one v5e chip, the prox kernel's call
+    carries ``local/update``, every convolution ``local/model`` and the
+    mask sync's sort ``consensus/masks``."""
+    import re
+    from repro.configs import get_config
+    from repro.configs.base import ConsensusSpec, ShapeConfig
+    from repro.kernels import ops
+    from repro.launch.mesh import make_host_mesh
+    from repro.models import build
+    from repro.train.engine import Engine
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = get_config("resnet18", smoke=True).replace(
+        prune_targets=("channel",))
+    eng = Engine(build(cfg), make_host_mesh(devices=topo.devices[:1]),
+                 ShapeConfig("s", "train", 0, 16),
+                 consensus=ConsensusSpec(levels=(2, 2), compact_from_level=1,
+                                         granularity="chip", node_size=2))
+    txt = eng.round_step_fn(False).lower(
+        eng.state_struct(), eng.superbatch_struct(),
+        jax.ShapeDtypeStruct((), jnp.float32)).compile().as_text()
+
+    def scopes_of(pattern):
+        lines = [l for l in txt.splitlines() if re.search(pattern, l)]
+        assert lines, pattern
+        # the innermost scope of each line's op_name
+        return {re.findall(r"(?:local|consensus)/\w+",
+                           re.search(r'op_name="([^"]*)"', l).group(1))[-1]
+                for l in lines}
+    assert scopes_of('"tpu_custom_call"') == {"local/update"}
+    assert scopes_of(r"\sconvolution\(") == {"local/model"}
+    assert scopes_of(r"\ssort\(") == {"consensus/masks"}

@@ -2,22 +2,35 @@
 prefetch so host batch synthesis overlaps device compute (the paper's Phase
 1 is compute-bound; input stall would pollute its timing benchmarks).
 
+A round's E batches are stacked where their leaves live.  Host arrays
+(``np.ndarray``) are stacked on the host, into arrays the feed reuses
+once released, and stay there, so the caller's single ``device_put``
+onto the round's input shardings is the only transfer: each device
+receives its own rows, and no stack waits behind the round running on
+the device (a device op queues behind it; a host-to-device transfer
+does not).  Device arrays are stacked on the device.
+
 Each stage is a ``dist.monitor.span``, so a profiler trace and the span
 counters show where the feed spends its time:
 
-  ``repro.feed.load``      superbatches: the E ``next()`` calls upstream
-  ``repro.feed.stack``     superbatches: stacking the E batches
-  ``repro.feed.produce``   prefetch worker: ``next()`` on its upstream
-  ``repro.feed.put_wait``  prefetch worker: blocked on a full queue
-  ``repro.feed.get_wait``  prefetch consumer: blocked on an empty queue
+  ``repro.feed.load``        superbatches: the E ``next()`` calls upstream
+  ``repro.feed.stack``       superbatches: stacking the E batches
+  ``repro.feed.host_stack``  within the stack: ``np.stack`` of the host
+                             leaves (once per superbatch that has any)
+  ``repro.feed.produce``     prefetch worker: ``next()`` on its upstream
+  ``repro.feed.put_wait``    prefetch worker: blocked on a full queue
+  ``repro.feed.get_wait``    prefetch consumer: blocked on an empty queue
 """
 from __future__ import annotations
 
+import collections
 import queue
+import sys
 import threading
 from typing import Iterator
 
 import jax
+import numpy as np
 
 from ..dist.monitor import span
 
@@ -26,7 +39,6 @@ def batches(stream, extra_inputs=(), shape=None, start_step: int = 0
             ) -> Iterator[dict]:
     """Infinite iterator of global batches (leading worker dim), including
     modality stubs (precomputed frame/patch embeddings per the assignment)."""
-    import numpy as np
     step = start_step
     while True:
         b = stream.batch_at(step)
@@ -41,16 +53,62 @@ def batches(stream, extra_inputs=(), shape=None, start_step: int = 0
         step += 1
 
 
+class _HostBuffers:
+    """Host arrays that a feed's stacks write into again.
+
+    A fresh array per round is mapped and page-faulted anew (50 MB for
+    a resnet18 round's images); on the v5e host that made the stack and
+    the loader's own allocations several times slower than a copy into
+    memory already in use.  An array is written again only when nothing
+    but this pool holds it: the consumer has dropped it and every
+    ``device_put`` that read it has let it go."""
+
+    def __init__(self):
+        self._bufs = collections.defaultdict(list)
+
+    def stack(self, xs):
+        key = ((len(xs),) + xs[0].shape, np.result_type(*xs))
+        for buf in self._bufs[key]:
+            if sys.getrefcount(buf) == 3:   # the list, `buf` and the call
+                return np.stack(xs, out=buf)
+        buf = np.stack(xs)
+        self._bufs[key].append(buf)
+        return buf
+
+
+def _stack_steps(bs: list, host: _HostBuffers):
+    """Stack a list of same-structured batches into one leading-dim
+    bundle, leaf by leaf: a leaf that is an ``np.ndarray`` in every batch
+    on the host, into ``host``'s arrays (it stays a host array), any
+    other with ``jax.numpy.stack``.  The values are the same either
+    way."""
+    treedef = jax.tree.structure(bs[0])
+    cols = list(zip(*(treedef.flatten_up_to(b) for b in bs)))
+    on_host = [all(isinstance(x, np.ndarray) for x in c) for c in cols]
+    out = [None if h else jax.numpy.stack(c) for c, h in zip(cols, on_host)]
+    if any(on_host):
+        with span("repro.feed.host_stack"):
+            out = [host.stack(c) if h else o
+                   for c, h, o in zip(cols, on_host, out)]
+    return treedef.unflatten(out)
+
+
 def superbatches(it: Iterator, e: int) -> Iterator:
     """Stack ``e`` consecutive global batches into one ``(E, W, ...)``
     superbatch — the unit the fused round executable scans over (one
     bundle per outer round; wrap with :func:`prefetch` so bundle
-    assembly overlaps the previous round's device compute)."""
+    assembly overlaps the previous round's device compute).
+
+    Host leaves are stacked on the host (:func:`_stack_steps`): put the
+    superbatch on the round's input shardings with one ``device_put``,
+    which sends each device its own rows and does not queue behind the
+    running round as a device stack does."""
+    host = _HostBuffers()
     while True:
         with span("repro.feed.load"):
             bs = [next(it) for _ in range(e)]
         with span("repro.feed.stack"):
-            sb = jax.tree.map(lambda *xs: jax.numpy.stack(xs), *bs)
+            sb = _stack_steps(bs, host)
         yield sb
 
 
@@ -58,12 +116,14 @@ def superbatch_chunks(it: Iterator, e: int, steps: int) -> Iterator:
     """Steps-bounded :func:`superbatches`: yields ``(n, superbatch)``
     covering exactly ``steps`` total steps in chunks of ``e`` plus one
     possibly-shorter tail (at most two distinct leading dims, so a
-    scanned consumer compiles at most twice)."""
+    scanned consumer compiles at most twice).  Stacked as
+    :func:`superbatches` stacks: host leaves stay on the host."""
+    host = _HostBuffers()
     done = 0
     while done < steps:
         n = min(e, steps - done)
         bs = [next(it) for _ in range(n)]
-        yield n, jax.tree.map(lambda *xs: jax.numpy.stack(xs), *bs)
+        yield n, _stack_steps(bs, host)
         done += n
 
 
